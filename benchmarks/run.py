@@ -37,6 +37,8 @@ def main() -> None:
                     help="smoke mode: run suites' smoke() and write metrics")
     args = ap.parse_args()
     pick = args.only or args.suite
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     failures = []
 
     if args.json:

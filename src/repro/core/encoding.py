@@ -381,6 +381,25 @@ def _common_prefix_len(a: bytes, b: bytes) -> int:
     return i
 
 
+def shrink_bytes(values: np.ndarray) -> np.ndarray:
+    """Retype a bytes array to its widest element (``S1`` when empty), the
+    dtype ``np.asarray(list_of_bytes, np.bytes_)`` gives the same values."""
+    width = int(np.strings.str_len(values).max()) if values.size else 0
+    return values.astype(f"S{max(width, 1)}")
+
+
+def _drop_prefix(values: np.ndarray, k: np.ndarray) -> np.ndarray:
+    """Row-wise ``values[i][k[i]:]`` over a fixed-width bytes array."""
+    if not k.any():
+        return shrink_bytes(values)
+    n, w = values.shape[0], values.dtype.itemsize
+    mat = np.ascontiguousarray(values).view(np.uint8).reshape(n, w)
+    idx = k.astype(np.int64)[:, None] + np.arange(w)
+    out = np.where(idx < w, np.take_along_axis(mat, np.minimum(idx, w - 1),
+                                               axis=1), 0).astype(np.uint8)
+    return shrink_bytes(out.view(f"S{w}").reshape(n))
+
+
 @dataclasses.dataclass
 class MultiPrefixEncoded(EncodedColumn):
     """Paper's multi-prefix encoding: a small dictionary of shared prefixes,
@@ -398,32 +417,29 @@ class MultiPrefixEncoded(EncodedColumn):
 
     @staticmethod
     def encode(values: np.ndarray, max_prefixes: int = 16) -> "MultiPrefixEncoded":
-        vals = [bytes(v) for v in values]
         # Greedy prefix pool: bucket rows by their first 4 bytes, take the
-        # longest common prefix within each of the most frequent buckets.
-        from collections import Counter
-        heads = Counter(v[:4] for v in vals)
+        # longest common prefix within each of the most frequent buckets
+        # (ties by first occurrence).  The common prefix of a bucket is the
+        # common prefix of its lexicographic min and max.
+        _, first, inv, counts = np.unique(values.astype("S4"),
+                                          return_index=True,
+                                          return_inverse=True,
+                                          return_counts=True)
         prefixes: List[bytes] = []
-        for head, _ in heads.most_common(max_prefixes):
-            bucket = [v for v in vals if v[:4] == head]
-            p = bucket[0]
-            for v in bucket[1:]:
-                p = p[: _common_prefix_len(p, v)]
-                if not p:
-                    break
+        for head in np.lexsort((first, -counts))[:max_prefixes]:
+            bucket = np.sort(values[inv.reshape(-1) == head])
+            lo, hi = bytes(bucket[0]), bytes(bucket[-1])
+            p = lo[: _common_prefix_len(lo, hi)]
             if len(p) >= 2:
                 prefixes.append(p)
-        ids = np.zeros(len(vals), np.int64)
-        suffixes: List[bytes] = []
-        for i, v in enumerate(vals):
-            best, best_len = -1, 0
-            for j, p in enumerate(prefixes):
-                if len(p) > best_len and v.startswith(p):
-                    best, best_len = j, len(p)
-            ids[i] = best + 1  # 0 == no prefix
-            suffixes.append(v[best_len:])
+        ids = np.zeros(len(values), np.int64)  # 0 == no prefix
+        best_len = np.zeros(len(values), np.int64)
+        for j, p in enumerate(prefixes):   # first longest matching prefix
+            hit = (len(p) > best_len) & np.strings.startswith(values, p)
+            ids[hit] = j + 1
+            best_len[hit] = len(p)
         return MultiPrefixEncoded(prefixes, _pack_codes(ids),
-                                  np.asarray(suffixes, dtype=np.bytes_),
+                                  _drop_prefix(values, best_len),
                                   values.dtype)
 
     def decode(self):
@@ -502,19 +518,12 @@ class InterColumnPrefixEncoded(EncodedColumn):
 
     @staticmethod
     def encode(ref: np.ndarray, values: np.ndarray) -> "InterColumnPrefixEncoded":
-        suf, exc_i, exc_v = [], [], []
-        for i, (a, b) in enumerate(zip(ref, values)):
-            a, b = bytes(a), bytes(b)
-            if b.startswith(a):
-                suf.append(b[len(a):])
-            else:
-                suf.append(b"")
-                exc_i.append(i)
-                exc_v.append(b)
-        return InterColumnPrefixEncoded(ref, np.asarray(suf, np.bytes_),
-                                        np.asarray(exc_i, np.int64),
-                                        np.asarray(exc_v, np.bytes_),
-                                        values.dtype)
+        ok = np.strings.startswith(values, ref)
+        # exception rows keep an empty suffix: drop the whole value
+        cut = np.where(ok, np.strings.str_len(ref), np.strings.str_len(values))
+        exc_i = np.nonzero(~ok)[0].astype(np.int64)
+        return InterColumnPrefixEncoded(ref, _drop_prefix(values, cut), exc_i,
+                                        shrink_bytes(values[exc_i]), values.dtype)
 
     def decode(self):
         out = [bytes(a) + bytes(s) for a, s in zip(self.ref, self.suffixes)]

@@ -26,7 +26,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .encoding import EncodedColumn, choose_encoding, payload_checksum
+from .encoding import (EncodedColumn, choose_encoding, payload_checksum,
+                       shrink_bytes)
 from .errors import BlockCorruption
 from .replica import collect as _collect_repairs, event_mark as _repair_mark
 from .relation import And, Column, ColType, PredOp, Predicate, Schema, Table
@@ -623,9 +624,7 @@ class LSMStore:
         with self._lock:
             version = self._ts if version is None else version
             self.freeze_memtable()
-            rows = self._merged_rows(version)
-            tbl = Table.from_rows(self.schema, list(rows.values())) \
-                if rows else Table.empty(self.schema)
+            tbl = self._merged_table(version)
             self.baseline = VirtualSSTable.build(self.schema, tbl, version,
                                                  self.block_rows)
             self._baseline_gen += 1
@@ -704,17 +703,37 @@ class LSMStore:
             mask &= p.eval(col)
         return [r for r, keep in zip(live, mask) if keep]
 
-    def _merged_rows(self, ts: int) -> Dict[Any, Dict[str, Any]]:
-        rows: Dict[Any, Dict[str, Any]] = {}
+    def _merged_table(self, ts: int) -> Table:
+        """The table as of ``ts``, built column-wise: decoded baseline rows
+        that no incremental version overrides, then the live incremental
+        rows.  Column types (string widths, NULL fills) come out exactly as
+        ``Table.from_rows`` over the merged rows would give them."""
         base = self.baseline
-        for i in range(base.nrows):
-            rows[base.pks[i].item() if hasattr(base.pks[i], "item") else base.pks[i]] = base.row(i)
-        for pk, v in self._incremental_effective(ts).items():
-            if v.op == DmlType.DELETE:
-                rows.pop(pk, None)
-            else:
-                rows[pk] = dict(v.row)
-        return rows
+        inc = self._incremental_effective(ts)
+        keep = np.ones(base.nrows, bool)
+        if inc and base.nrows:
+            keep[[i for i in map(base.locate, inc) if i >= 0]] = False
+        cols: Dict[str, Column] = {}
+        for spec in self.schema.columns:
+            cst = base.cols[spec.name]
+            vals = cst.decode_all()[keep] if base.nrows \
+                else np.empty((0,), spec.ctype.np_dtype)
+            nulls = None
+            if cst.null_blocks is not None and base.nrows:
+                nulls = np.concatenate(cst.null_blocks)[keep]
+                vals[nulls] = b"" if spec.ctype == ColType.STR else 0
+                nulls = nulls if nulls.any() else None
+            if spec.ctype != ColType.STR:
+                vals = vals.astype(spec.ctype.np_dtype, copy=False)
+            cols[spec.name] = Column(spec, vals, nulls)
+        tbl = Table(self.schema, cols)
+        live = [v.row for v in inc.values() if v.op != DmlType.DELETE]
+        if live:
+            tbl = tbl.concat(Table.from_rows(self.schema, live))
+        for name, c in tbl.columns.items():
+            if c.spec.ctype == ColType.STR:
+                c.values = shrink_bytes(c.values.astype(np.bytes_, copy=False))
+        return tbl
 
     def get(self, pk: Any, ts: Optional[int] = None) -> Optional[Dict[str, Any]]:
         ts = self._ts if ts is None else ts
@@ -778,15 +797,17 @@ class LSMStore:
         base_nulls: Dict[str, Optional[np.ndarray]] = {}
         if base_idx.size:
             blk_ids = np.unique(base_idx // self.block_rows)
+            # base_idx ascends, so each block's rows are one slice of it
+            cuts = np.searchsorted(base_idx, np.append(blk_ids, blk_ids[-1] + 1)
+                                   * self.block_rows)
             for name in columns:
                 parts = []
                 nparts = []
                 cst = base.cols[name]
-                for b in blk_ids:
+                for i, b in enumerate(blk_ids):
                     lo = int(b) * self.block_rows
                     dec = cst.decode_block(int(b))
-                    sel = base_idx[(base_idx >= lo)
-                                   & (base_idx < lo + self.block_rows)] - lo
+                    sel = base_idx[cuts[i]:cuts[i + 1]] - lo
                     parts.append(dec[sel])
                     bn = cst.block_nulls(int(b))
                     nparts.append(np.zeros(sel.shape[0], bool)

@@ -64,8 +64,8 @@ from . import pushdown as _pd
 from . import replica as _replica
 from .engine import (Query, VectorEngine, _item, null_aware_key_codes,
                      null_last_key, pack_sort_keys)
-from .errors import (BlockCorruption, Deadline, KeyPackError, QueryTimeout,
-                     RouteExhausted, ShardFailure)
+from .errors import (BlockCorruption, Deadline, KernelLaunchError,
+                     KeyPackError, QueryTimeout, RouteExhausted, ShardFailure)
 from .lsm import LSMStore, ScanStats, VirtualSSTable
 from .relation import ColType, Column
 from .skipping import Verdict
@@ -623,6 +623,11 @@ class ShardedScanExecutor:
             stats = ScanStats()
         fp = faultinject.active()
         lock = threading.Lock()
+        # shards whose primary has failed an attempt: their retry loop owns
+        # them, so the hedge (which races stragglers, not failures) skips
+        # them — a hedge twin would be an extra attempt outside the retry
+        # budget and the per-shard breaker's fail-fast
+        failing: set = set()
 
         def run(shard: BlockShard, attempt: int):
             if fp is not None:
@@ -651,6 +656,8 @@ class ShardedScanExecutor:
                 # re-raise as typed ShardFailure
                 except Exception as e:
                     last = e
+                    with lock:
+                        failing.add(shard.shard_id)
                     if attempt + 1 >= attempts:
                         break
                     with lock:
@@ -721,12 +728,16 @@ class ShardedScanExecutor:
                 if (self.hedge and hedged is None and pending
                         and len(done_times) >= 2):
                     p95 = float(np.percentile(done_times, 95))
-                    if now - t0 > max(2.0 * p95, p95 + 0.05):
+                    with lock:
+                        slow = [futs[p] for p in pending
+                                if results[futs[p]] is _PENDING
+                                and active[futs[p]].shard_id not in failing]
+                    if slow and now - t0 > max(2.0 * p95, p95 + 0.05):
                         # all primaries started together, so every
-                        # outstanding shard is a straggler; re-dispatch
-                        # the lowest position for determinism
-                        i = min(futs[p] for p in pending
-                                if results[futs[p]] is _PENDING)
+                        # outstanding shard that has not failed is a
+                        # straggler; re-dispatch the lowest position for
+                        # determinism
+                        i = min(slow)
                         hf = pool.submit(run_hedge, active[i])
                         futs[hf] = i
                         pending.add(hf)
@@ -924,11 +935,7 @@ class ShardedScanExecutor:
                                                   block_mask, mesh, tile,
                                                   stats, ops)
                     break
-                except (QueryTimeout, BlockCorruption):
-                    raise
-                # lint: allow(broad-except) — device-launch rung: a
-                # failed collective retries in-route, then drops a rung
-                except Exception as e:
+                except KernelLaunchError as e:
                     if rattempt == 0:
                         stats.kernel_retries += 1
                         if deadline is not None:
@@ -965,11 +972,7 @@ class ShardedScanExecutor:
                 partials = [tuple(np.asarray(x) for x in o)
                             for o in launched]
                 out = tree_reduce(partials, device_partial_combine) + (None,)
-            except (QueryTimeout, BlockCorruption):
-                raise
-            # lint: allow(broad-except) — device-launch rung: any
-            # per-shard launch failure degrades to host pushdown
-            except Exception as e:
+            except KernelLaunchError as e:
                 # rung 2: per-shard kernel launches failed too — undo the
                 # device accounting (the host pushdown path re-counts with
                 # += as it scans) and hand the query back to the caller
@@ -1002,10 +1005,10 @@ class ShardedScanExecutor:
             k = None          # packed order is lexicographic over the key
                               # columns in order: only prefix sorts slice
         stats.topk_pushdown = k is not None
-        out = ops.sharded_scan_agg(deltas, bases, counts, plan.lo, plan.hi,
-                                   codes, values, ndv=stage.ndv,
-                                   block_mask=bmask, mesh=mesh,
-                                   coalesce=tile, topk=k or 0)
+        out = _pd.run_device_kernel(
+            "collective", ops.sharded_scan_agg, deltas, bases, counts,
+            plan.lo, plan.hi, codes, values, bmask, ndv=stage.ndv, mesh=mesh,
+            coalesce=tile, topk=k or 0)
         if k is not None:
             g_ids, g_cnt, g_sums, g_mins, g_maxs, total = out
             stats.actual_rows = int(total)
@@ -1081,32 +1084,36 @@ def launch_shard_kernels(plan, stage, shards: Sequence[BlockShard],
                          deadline=None, stats=None):
     """Per-shard-launch device route: dispatch the fused kernel for every
     shard's block slice (round-robin placement by shard id) and return the
-    raw per-shard outputs.  Every kernel is launched before any result is
-    blocked on — jax dispatch is async, so on a multi-device mesh the
-    shards overlap.  The per-query ``deadline`` is checked between
-    launches so ``deadline_s`` binds on this route too.  Shared by
-    ``ShardedScanExecutor._try_device`` and the route benchmark, so the
-    bench always measures the loop the engine runs."""
+    raw per-shard outputs.  Every shard's launch is compiled first, so a
+    compile error fails the query before anything runs; then every kernel
+    is dispatched before any result is blocked on — jax dispatch is async,
+    so on a multi-device mesh the shards overlap — and a runtime fault of
+    any of them raises ``KernelLaunchError``.  The per-query ``deadline``
+    is checked between launches so ``deadline_s`` binds on this route too.
+    Shared by ``ShardedScanExecutor._try_device`` and the route benchmark,
+    so the bench always measures the loop the engine runs."""
     import jax
     from ..kernels import ops
     fp = faultinject.active()
-    outs = []
+    launches = []
     for shard in shards:
+        sl = slice(shard.lo_block, shard.hi_block)
+        dev = devices[shard.shard_id % len(devices)]
+        ins = [stage.deltas[sl], stage.bases[sl], stage.counts[sl], plan.lo,
+               plan.hi, stage.codes[sl], stage.values[sl], block_mask[sl]]
+        if dev is not None:
+            ins = [x if np.isscalar(x) else jax.device_put(x, dev)
+                   for x in ins]
+        launches.append((_pd.compile_device_kernel(
+            ops.fused_scan_agg, *ins, ndv=stage.ndv, coalesce=tile), ins))
+    outs = []
+    for exe, ins in launches:
         if deadline is not None:
             deadline.check(stats, completed=len(outs), total=len(shards))
         if fp is not None:
             fp.on_kernel_launch("host")
-        sl = slice(shard.lo_block, shard.hi_block)
-        dev = devices[shard.shard_id % len(devices)]
-        ins = [stage.deltas[sl], stage.bases[sl], stage.counts[sl],
-               stage.codes[sl], stage.values[sl], block_mask[sl]]
-        if dev is not None:
-            ins = [jax.device_put(x, dev) for x in ins]
-        outs.append(ops.fused_scan_agg(ins[0], ins[1], ins[2], plan.lo,
-                                       plan.hi, ins[3], ins[4],
-                                       ndv=stage.ndv, block_mask=ins[5],
-                                       coalesce=tile))
-    return outs
+        outs.append(_pd.dispatch_device_kernel("host", exe, *ins))
+    return _pd.await_device_kernels("host", outs)
 
 
 def device_partial_combine(a, b):

@@ -63,7 +63,8 @@ from . import cost
 from . import faultinject
 from .encoding import DeltaFOREncoded, DictEncoded, PlainEncoded
 from .engine import Query, VectorEngine, _item
-from .errors import BlockCorruption, Deadline, QueryTimeout
+from .errors import (BlockCorruption, Deadline, KernelLaunchError,
+                     QueryTimeout)
 from .lsm import BlockView, LSMStore, ScanStats, eval_block_pred
 from .replica import (collect as _collect_repairs,
                       event_mark as _repair_mark)
@@ -596,10 +597,10 @@ class PushdownExecutor:
         def launch(mask):
             if fp is not None:
                 fp.on_kernel_launch("pushdown")
-            return ops.fused_scan_agg(
-                stage.deltas, stage.bases, stage.counts, plan.lo, plan.hi,
-                stage.codes, stage.values, ndv=stage.ndv,
-                block_mask=mask, coalesce=tile)
+            return run_device_kernel(
+                "pushdown", ops.fused_scan_agg, stage.deltas, stage.bases,
+                stage.counts, plan.lo, plan.hi, stage.codes, stage.values,
+                mask, ndv=stage.ndv, coalesce=tile)
 
         try:
             if deadline is not None and nblocks > chunk:
@@ -629,11 +630,7 @@ class PushdownExecutor:
                 g_cnt, g_sums, g_mins, g_maxs = merged  # identity planes
             else:
                 g_cnt, g_sums, g_mins, g_maxs = launch(block_mask)
-        except (QueryTimeout, BlockCorruption):
-            raise
-        # lint: allow(broad-except) — device→host degrade point: any
-        # launch failure falls back to the host scan, stamped in stats
-        except Exception as e:
+        except KernelLaunchError as e:
             # degrade to the host pushdown scan: undo the device accounting
             # (filter_blocks re-counts with += as it scans)
             stats.degraded.append(
@@ -682,8 +679,63 @@ class DeviceStage:
     ndv: Tuple[int, ...]
 
 
-_DEVICE_MAX_GROUPS = 1 << 20           # packed-domain cap: G·(1+3V) f32 VMEM
+_DEVICE_MAX_GROUPS = 1 << 12           # largest packed group domain the
+                                       # kernel's VMEM accumulators compile
+                                       # for on a v5e (test_tpu_compile.py)
 _DEVICE_BIG = 1 << 30                  # int32-safe bound for staged ints
+
+
+_COMPILED: Dict[tuple, Any] = {}      # (kernel, static, arg avals) → exe
+_COMPILED_MAX = 64                     # distinct launch shapes kept
+
+
+def _arg_key(a) -> tuple:
+    return (np.shape(a), str(np.result_type(a)), getattr(a, "sharding", None))
+
+
+def compile_device_kernel(kernel, *args, **static):
+    """The compiled executable of a jitted kernel wrapper for these
+    argument shapes, dtypes and placements (``static`` are its static
+    arguments), compiled once and then reused.  A trace, lowering or
+    compile error propagates and fails the query."""
+    key = (kernel, tuple(sorted(static.items())),
+           tuple(_arg_key(a) for a in args))
+    exe = _COMPILED.get(key)
+    if exe is None:
+        exe = kernel.lower(*args, **static).compile()
+        if len(_COMPILED) >= _COMPILED_MAX:
+            _COMPILED.clear()
+        _COMPILED[key] = exe
+    return exe
+
+
+def dispatch_device_kernel(route: str, exe, *args):
+    """Dispatch a compiled launch without waiting for it; a runtime fault
+    raised at dispatch is wrapped as ``KernelLaunchError``."""
+    import jax
+    try:
+        return exe(*args)
+    except jax.errors.JaxRuntimeError as e:
+        raise KernelLaunchError(route, e) from e
+
+
+def await_device_kernels(route: str, outs):
+    """Block on dispatched launches; a runtime fault of any of them is
+    wrapped as ``KernelLaunchError``, the one error the device rungs
+    degrade on."""
+    import jax
+    try:
+        return jax.block_until_ready(outs)
+    except jax.errors.JaxRuntimeError as e:
+        raise KernelLaunchError(route, e) from e
+
+
+def run_device_kernel(route: str, kernel, *args, **static):
+    """Compile (once per launch shape), run and wait for one device launch:
+    compile errors propagate, runtime faults raise ``KernelLaunchError``."""
+    exe = compile_device_kernel(kernel, *args, **static)
+    return await_device_kernels(route,
+                                dispatch_device_kernel(route, exe, *args))
 
 
 def plan_device(store: LSMStore, q: Query) -> Optional[DevicePlan]:

@@ -14,16 +14,23 @@ into a single radix code ``sum_k codes[k] * stride[k]`` on device — the
 sequence-preserving encoding of ``engine.pack_sort_keys``, executed on the
 VPU so multi-column group-bys cost one one-hot contraction, not K.
 
-Values are **multi-column**: ``V`` f32 value planes aggregate in the same
-pass; sums/counts use the one-hot MXU contraction of ``dict_groupby``,
-min/max ride the VPU on the masked one-hot.  The zone-map skip uses the
-scalar-prefetch visit-list trick: the wrapper prunes blocks with the
-skipping index and the kernel only ever DMAs the surviving blocks.
+Layout is lane-major: a block's rows lie on the 128-wide lanes, so every
+per-row plane is a ``[1, C]`` or ``[K|V, C]`` slab and nothing is ever
+broadcast across lanes.  Each grid step takes one ``C``-row chunk of one
+block and builds the one-hot ``hit [G, C]`` (G = prod(ndv) padded to a
+sublane multiple) by comparing a sublane iota with the packed keys.  Sums
+are one ``[G, C] x [V, C]^T`` MXU contraction at HIGHEST precision (the
+one-hot is exact in any precision, the values are f32); counts, mins and
+maxs reduce ``hit`` along the lanes.  The four accumulators (count i32
+``[G, 1]``, sum/min/max f32 ``[G, V]``) are the kernel's outputs, resident
+in VMEM across the whole grid.  ``C`` is the largest 128-multiple divisor
+of the block height that keeps the one-hot under ``ONEHOT_ELEMS``.
 
-Grid = (Nb,) sequential; [1 + 3V, G] f32 accumulator (count, then per value
-column sum/min/max) lives in VMEM scratch.  G = prod(ndv) padded to a
-128-lane multiple by the wrapper.  A query with no predicate passes
-all-zero deltas/bases with lo = hi = 0, selecting every valid row.
+The zone-map skip uses the scalar-prefetch visit-list trick: the wrapper
+prunes blocks with the skipping index and the kernel only ever DMAs the
+surviving blocks; per-block FOR bases and row counts ride in SMEM beside
+the visit list.  A query with no predicate passes all-zero deltas/bases
+with lo = hi = 0, selecting every valid row.
 """
 from __future__ import annotations
 
@@ -36,6 +43,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 POS_INF = 1e30
+LANES = 128
+SUBLANES = 8
+ONEHOT_ELEMS = 1 << 17        # one-hot chunk budget: G * C f32 = 512 KiB
 
 
 def coalesce_blocks(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
@@ -56,11 +66,11 @@ def coalesce_blocks(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
     Precondition: within a tile, every member after a partially-filled
     member must be empty — the baseline layout (only the globally-last
     block is partial) and trailing zero-count padding both satisfy it, so
-    valid rows stay a prefix and the kernel's ``rowid < nvalid`` check
+    valid rows stay a prefix and the kernel's ``row < count`` check
     carries over.
 
-    Expects the general layout (codes [Nb, K, Bk], values [Nb, V, Bk]).
-    """
+    Expects the general layout (deltas [Nb, Bk], codes [Nb, K, Bk], values
+    [Nb, V, Bk]) and returns it with ``Bk * factor`` rows per tile."""
     nb, bk = deltas.shape
     f = max(int(factor), 1)
     nb2 = -(-nb // f)
@@ -87,59 +97,64 @@ def coalesce_blocks(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
     return deltas2, base2, counts2, codes2, values2, mask2
 
 
-def _fused_kernel(bids_ref, cnt_ref,                     # scalar prefetch
-                  deltas_ref, bases_ref, counts_ref, codes_ref, values_ref,
-                  bounds_ref, out_ref, acc_scr, *, block_k: int, g: int,
-                  n_vals: int, strides: Tuple[int, ...]):
-    j = pl.program_id(0)
-    nv = pl.num_programs(0)
-    rows_acc = 1 + 3 * n_vals
+def _fused_kernel(bids_ref, cnt_ref, bases_ref, counts_ref, bounds_ref,
+                  deltas_ref, codes_ref, values_ref,
+                  cnt_out, sum_out, min_out, max_out, *,
+                  chunk: int, strides: Tuple[int, ...]):
+    j, c = pl.program_id(0), pl.program_id(1)
+    g, n_vals = sum_out.shape
 
-    @pl.when(j == 0)
+    @pl.when((j == 0) & (c == 0))
     def _init():
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows_acc, g), 0)
-        slot = (row - 1) % 3            # 0 = sum, 1 = min, 2 = max (row > 0)
-        acc_scr[...] = jnp.where((row > 0) & (slot == 1), POS_INF,
-                                 jnp.where((row > 0) & (slot == 2),
-                                           -POS_INF, 0.0))
+        cnt_out[...] = jnp.zeros_like(cnt_out)
+        sum_out[...] = jnp.zeros_like(sum_out)
+        min_out[...] = jnp.full_like(min_out, POS_INF)
+        max_out[...] = jnp.full_like(max_out, -POS_INF)
 
     @pl.when(j < cnt_ref[0])
     def _body():
-        deltas = deltas_ref[0].astype(jnp.int32)          # [Bk]
-        base = bases_ref[0, 0]
-        nvalid = counts_ref[0, 0]
-        lo = bounds_ref[0, 0] - base                      # encoded-domain bound
-        hi = bounds_ref[0, 1] - base
-        codes = codes_ref[0]                              # [K, Bk]
+        b = bids_ref[j]
+        base = bases_ref[b]
+        lo = bounds_ref[0] - base                         # encoded-domain
+        hi = bounds_ref[1] - base                         # bounds
+        deltas = deltas_ref[0]                            # [1, C]
         # device-side pack_sort_keys: radix-pack the K code planes
-        packed = codes[0] * strides[0]
+        packed = codes_ref[0, 0:1, :] * strides[0]
         for k in range(1, len(strides)):
-            packed = packed + codes[k] * strides[k]
-        sel = (deltas >= lo) & (deltas <= hi)             # [Bk]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (block_k, g), 1)
-        rowid = jax.lax.broadcasted_iota(jnp.int32, (block_k, g), 0)
-        onehot = ((packed[:, None] == lanes) & sel[:, None]
-                  & (rowid < nvalid)).astype(jnp.float32)
-        a = acc_scr[...]
-        parts = [a[0:1] + onehot.sum(axis=0)[None, :]]                  # [1,G]
+            packed = packed + codes_ref[0, k:k + 1, :] * strides[k]
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) + c * chunk
+        sel = (deltas >= lo) & (deltas <= hi) & (row < counts_ref[b])
+        key = jnp.where(sel, packed, -1)                  # -1: no group
+        hit = jax.lax.broadcasted_iota(jnp.int32, (g, chunk), 0) == key
+        onehot = hit.astype(jnp.float32)                  # [G, C]
+        vals = values_ref[0]                              # [V, C] f32
+        cnt_out[...] += jnp.sum(onehot, axis=1,
+                                keepdims=True).astype(jnp.int32)
+        sum_out[...] += jax.lax.dot_general(
+            onehot, vals, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        col = jax.lax.broadcasted_iota(jnp.int32, (g, n_vals), 1)
+        mins, maxs = min_out[...], max_out[...]
         for v in range(n_vals):
-            vals = values_ref[0, v].astype(jnp.float32)                 # [Bk]
-            sums = jax.lax.dot_general(vals[None, :], onehot,
-                                       (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-            mins = jnp.where(onehot > 0, vals[:, None],
-                             POS_INF).min(axis=0)[None, :]
-            maxs = jnp.where(onehot > 0, vals[:, None],
-                             -POS_INF).max(axis=0)[None, :]
-            r = 1 + 3 * v
-            parts += [a[r:r + 1] + sums,
-                      jnp.minimum(a[r + 1:r + 2], mins),
-                      jnp.maximum(a[r + 2:r + 3], maxs)]
-        acc_scr[...] = jnp.concatenate(parts, axis=0)
+            vrow = values_ref[0, v:v + 1, :]              # [1, C]
+            mn = jnp.min(jnp.where(hit, vrow, POS_INF), axis=1, keepdims=True)
+            mx = jnp.max(jnp.where(hit, vrow, -POS_INF), axis=1,
+                         keepdims=True)
+            mins = jnp.where(col == v, jnp.minimum(mins, mn), mins)
+            maxs = jnp.where(col == v, jnp.maximum(maxs, mx), maxs)
+        min_out[...] = mins
+        max_out[...] = maxs
 
-    @pl.when(j == nv - 1)
-    def _emit():
-        out_ref[...] = acc_scr[...]
+
+def _chunk_rows(bk: int, g: int) -> int:
+    """Rows per grid step: the largest 128-multiple divisor of ``bk`` whose
+    ``[g, C]`` one-hot stays within ``ONEHOT_ELEMS`` (at least 128)."""
+    best = LANES
+    for c in range(LANES, bk + 1, LANES):
+        if bk % c == 0 and g * c <= ONEHOT_ELEMS:
+            best = c
+    return best
 
 
 def _normalize(codes: jax.Array, values: jax.Array,
@@ -181,45 +196,47 @@ def fused_scan_agg(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
     Nb, Bk = deltas.shape
     legacy, codes3, values3, ndv_t, strides, P = _normalize(codes, values, ndv)
     K, V = codes3.shape[1], values3.shape[1]
-    G = ((P + 127) // 128) * 128
-    R = 1 + 3 * V
+    G = -(-P // SUBLANES) * SUBLANES
+    Bkp = -(-Bk // LANES) * LANES         # rows padded onto whole lanes; the
+    if Bkp != Bk:                         # row < count check masks the pad
+        pad = ((0, 0), (0, 0), (0, Bkp - Bk))
+        deltas = jnp.pad(deltas, pad[1:])
+        codes3, values3 = jnp.pad(codes3, pad), jnp.pad(values3, pad)
+    C = _chunk_rows(Bkp, G)
     if block_mask is None:
         block_mask = jnp.ones((Nb,), bool)
     order = jnp.argsort(~block_mask, stable=True)
     cnt = block_mask.sum().astype(jnp.int32)
     idx = jnp.minimum(jnp.arange(Nb), jnp.maximum(cnt - 1, 0))
     bids = jnp.take_along_axis(order, idx, axis=0).astype(jnp.int32)
-    bounds = jnp.asarray([[lo, hi]], jnp.int32)
+    bounds = jnp.stack([jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32)])
 
-    kernel = functools.partial(_fused_kernel, block_k=Bk, g=G, n_vals=V,
-                               strides=strides)
-    out = pl.pallas_call(
+    def rows(n):                          # block bids[j], chunk c
+        return pl.BlockSpec((1, n, C), lambda j, c, bids, *_: (bids[j], 0, c))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda j, c, *_: (0, 0))
+
+    kernel = functools.partial(_fused_kernel, chunk=C, strides=strides)
+    g_cnt, sums, mins, maxs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(Nb,),
-            in_specs=[
-                pl.BlockSpec((1, Bk), lambda j, bids, cnt: (bids[j], 0)),
-                pl.BlockSpec((1, 1), lambda j, bids, cnt: (bids[j], 0)),
-                pl.BlockSpec((1, 1), lambda j, bids, cnt: (bids[j], 0)),
-                pl.BlockSpec((1, K, Bk),
-                             lambda j, bids, cnt: (bids[j], 0, 0)),
-                pl.BlockSpec((1, V, Bk),
-                             lambda j, bids, cnt: (bids[j], 0, 0)),
-                pl.BlockSpec((1, 2), lambda j, bids, cnt: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((R, G), lambda j, bids, cnt: (0, 0)),
-            scratch_shapes=[pltpu.VMEM((R, G), jnp.float32)],
+            num_scalar_prefetch=5,
+            grid=(Nb, Bkp // C),
+            in_specs=[rows(1), rows(K), rows(V)],
+            out_specs=[whole((G, 1)), whole((G, V)), whole((G, V)),
+                       whole((G, V))],
         ),
-        out_shape=jax.ShapeDtypeStruct((R, G), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((G, 1), jnp.int32)]
+        + [jax.ShapeDtypeStruct((G, V), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(bids, cnt[None], deltas,
-      bases.reshape(Nb, 1).astype(jnp.int32),
-      counts.reshape(Nb, 1).astype(jnp.int32),
-      codes3.astype(jnp.int32), values3.astype(jnp.float32), bounds)
-    g_cnt = out[0, :P].astype(jnp.int32)
-    per_v = out[1:].reshape(V, 3, G)
-    sums, mins, maxs = per_v[:, 0, :P], per_v[:, 1, :P], per_v[:, 2, :P]
+    )(bids, cnt[None], bases.astype(jnp.int32), counts.astype(jnp.int32),
+      bounds, deltas.astype(jnp.int32).reshape(Nb, 1, Bkp),
+      codes3.astype(jnp.int32), values3.astype(jnp.float32))
+    g_cnt = g_cnt[:P, 0]
+    sums, mins, maxs = sums[:P].T, mins[:P].T, maxs[:P].T
     if legacy:
         return g_cnt, sums[0], mins[0], maxs[0]
     return g_cnt, sums, mins, maxs
@@ -241,7 +258,7 @@ def sharded_scan_agg(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
     One ``shard_map`` launch places S/msize shard slices on each device;
     a device folds its slices into the block grid of ONE fused-kernel
     launch (zero-count padding blocks are masked off by the visit list),
-    and the [1+3V, G] accumulators tree-reduce across the mesh via
+    and the per-group accumulators tree-reduce across the mesh via
     psum (count, sums) / pmin / pmax — log-depth on a real torus.
 
     With ``topk = k > 0`` the reduced accumulator is additionally sliced on
@@ -250,7 +267,6 @@ def sharded_scan_agg(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
     by a key-column prefix): returns (ids [k], count [k], sums [V, k],
     mins, maxs, total_rows) and only O(k) lanes cross back to the host.
     Otherwise returns (count [P], sums [V, P], mins, maxs) replicated."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P_
 
     S, Nb, Bk = deltas.shape
@@ -285,6 +301,6 @@ def sharded_scan_agg(deltas: jax.Array, bases: jax.Array, counts: jax.Array,
         return (ids.astype(jnp.int32), cnt[ids], sums[:, ids], mins[:, ids],
                 maxs[:, ids], total)
 
-    f = shard_map(body, mesh=mesh, in_specs=(P_("scan"),) * 6,
-                  out_specs=P_(), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P_("scan"),) * 6,
+                      out_specs=P_(), check_vma=False)
     return f(deltas, bases, counts, codes, values, block_mask)

@@ -4,9 +4,10 @@ Dispatch policy:
   * on TPU          → compiled Pallas kernels;
   * on CPU (tests)  → the same kernels in interpret mode (bit-identical
                       semantics, Python-emulated grid);
-  * inside the distributed dry-run (`REPRO_FORCE_REF=1` or use_kernels=False
-    at the model layer) → the pure-jnp references from ref.py, so HLO cost
-    analysis reflects the math, not the interpreter.
+  * any other backend is an error: there is no silent interpreter there.
+  * the other kernels also honour `REPRO_FORCE_REF=1` (the distributed
+    dry-run's pure-jnp references from ref.py); the fused scan-agg
+    wrappers that serve queries never swap in a reference.
 """
 from __future__ import annotations
 
@@ -29,10 +30,12 @@ from .fused_scan_agg import sharded_scan_agg as _sharded_scan_agg
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    """True on TPU, False on CPU (interpret mode); any other backend has no
+    kernel path and raises."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"no Pallas TPU kernel path on backend {backend!r}")
+    return backend == "tpu"
 
 
 def _force_ref() -> bool:
@@ -82,17 +85,14 @@ def columnar_scan(deltas, bases, counts, lo, hi, values=None, block_mask=None):
 
 
 @functools.partial(jax.jit, static_argnames=("ndv", "coalesce"))
-def fused_scan_agg(deltas, bases, counts, lo, hi, codes, values, *, ndv,
-                   block_mask=None, coalesce=1):
+def fused_scan_agg(deltas, bases, counts, lo, hi, codes, values,
+                   block_mask=None, *, ndv, coalesce=1):
     """``ndv`` is an int (legacy single group key, 2-D codes/values) or a
     per-key tuple (multi-key: codes [Nb, K, Bk], values [Nb, V, Bk]).
     ``coalesce`` > 1 fuses that many adjacent blocks into one kernel tile
     before launch (selectivity-matched tile shapes, see
     ``fused_scan_agg.coalesce_blocks``); the grouped results are identical
     for any factor."""
-    if _force_ref():
-        return ref.ref_fused_scan_agg(deltas, bases, counts, lo, hi, codes,
-                                      values, ndv, block_mask)
     if coalesce and int(coalesce) > 1:
         legacy = (codes.ndim == 2 and values.ndim == 2
                   and not isinstance(ndv, (tuple, list)))
@@ -116,8 +116,8 @@ def fused_scan_agg(deltas, bases, counts, lo, hi, codes, values, *, ndv,
 
 @functools.partial(jax.jit, static_argnames=("ndv", "mesh", "coalesce",
                                              "topk"))
-def sharded_scan_agg(deltas, bases, counts, lo, hi, codes, values, *, ndv,
-                     mesh, block_mask=None, coalesce=1, topk=0):
+def sharded_scan_agg(deltas, bases, counts, lo, hi, codes, values,
+                     block_mask=None, *, ndv, mesh, coalesce=1, topk=0):
     """Single-launch sharded device fan-out: inputs carry a leading shard
     axis [S, ...] split over ``mesh``'s 'scan' axis by one ``shard_map``
     launch; each device runs the fused scan-agg kernel over its shard
@@ -127,10 +127,6 @@ def sharded_scan_agg(deltas, bases, counts, lo, hi, codes, values, *, ndv,
     on device (returns (ids, count, sums, mins, maxs, total_rows))."""
     if block_mask is None:
         block_mask = jnp.ones(deltas.shape[:2], bool)
-    if _force_ref():
-        return ref.ref_sharded_scan_agg(deltas, bases, counts, lo, hi,
-                                        codes, values, ndv, block_mask,
-                                        topk=topk)
     return _sharded_scan_agg(deltas, bases, counts, lo, hi, codes, values,
                              ndv, block_mask, mesh, coalesce=int(coalesce),
                              topk=int(topk), interpret=not _on_tpu())

@@ -21,34 +21,23 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.sharding import MeshRules
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]
-                     ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; older ones
-    default to Auto axes anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh(shape: Tuple[int, ...] = (2, 2),
                     axes: Tuple[str, ...] = ("data", "model")
                     ) -> jax.sharding.Mesh:
     """Tiny mesh for CPU multi-device tests (requires host-device override)."""
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_scan_mesh(n_shards: Optional[int] = None) -> jax.sharding.Mesh:
@@ -62,7 +51,7 @@ def make_scan_mesh(n_shards: Optional[int] = None) -> jax.sharding.Mesh:
     sequentially inside one launch."""
     ndev = len(jax.devices())
     size = max(1, min(n_shards or ndev, ndev))
-    return make_mesh_compat((size,), ("scan",))
+    return jax.make_mesh((size,), ("scan",), axis_types=(AxisType.Auto,))
 
 
 def scan_launch_shape(n_shards: int,

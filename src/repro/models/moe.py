@@ -29,7 +29,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -181,11 +180,11 @@ def moe_ffn(cfg: ModelConfig, rules: MeshRules, lp: Dict[str, Any],
         out = _local_combine(y, meta, T).reshape(Bl, Sl, d)
         return out, (dropped / (T * k)).astype(jnp.float32)[None]
 
-    out, dropped = shard_map(
+    out, dropped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w2_spec),
         out_specs=(x_spec, P(batch_axes if batch_axes else None)),
-        check_rep=False,
+        check_vma=False,
     )(x, router, w1 if E_pad == E else jnp.pad(w1, ((0, E_pad - E), (0, 0), (0, 0))),
       w3 if E_pad == E else jnp.pad(w3, ((0, E_pad - E), (0, 0), (0, 0))),
       w2 if E_pad == E else jnp.pad(w2, ((0, E_pad - E), (0, 0), (0, 0))))

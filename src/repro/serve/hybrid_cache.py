@@ -37,7 +37,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -268,12 +267,12 @@ def hybrid_attention(cfg: ModelConfig, rules: MeshRules,
         gacc = jax.lax.psum(acc * w[..., None], kv_axes)
         return gacc / jnp.maximum(gl, 1e-30)[..., None]
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), blk_spec, blk_spec, sc_spec, sc_spec, sc_spec, P(),
                   P(), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(qg, layer_cache["kq"], layer_cache["vq"], layer_cache["kscale"],
       layer_cache["vscale"], layer_cache["sketch"], layer_cache["n_blocks"],
       layer_cache["tail_k"], layer_cache["tail_v"], layer_cache["tail_len"])

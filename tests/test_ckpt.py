@@ -104,8 +104,8 @@ def test_atomic_write_never_leaves_partial(tmp_path):
 
 def test_reshard_roundtrip_single_device():
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import make_smoke_mesh
+    mesh = make_smoke_mesh((1,), ("data",))
     params, _ = tiny_state()
     pspecs = {"w": P("data", None), "layers": {"ln": P()}}
     placed = reshard(params, mesh, pspecs)

@@ -210,10 +210,11 @@ def test_wal_encode_decode_roundtrip(recs):
             (kind, seq, ts, gen, data)
 
 
-@given(st.lists(wal_record_strategy, min_size=1, max_size=8),
-       st.data())
+@given(recs=st.lists(wal_record_strategy, min_size=1, max_size=8),
+       data=st.data())
 @settings(max_examples=60, deadline=None)
-def test_wal_single_bit_flip_never_silently_decodes(recs, data, tmp_path):
+def test_wal_single_bit_flip_never_silently_decodes(recs, data,
+                                                    tmp_path_factory):
     """Flip one bit anywhere in the log: scanning must either raise a typed
     RecoveryError or exclude the damaged record (a flip in a length field
     can make the tail read as torn) — it may never yield a record whose
@@ -225,7 +226,7 @@ def test_wal_single_bit_flip_never_silently_decodes(recs, data, tmp_path):
     i = data.draw(st.integers(0, len(buf) - 1))
     bit = data.draw(st.integers(0, 7))
     buf[i] ^= 1 << bit
-    path = str(tmp_path / "flip.wal")
+    path = str(tmp_path_factory.mktemp("flip") / "flip.wal")  # per example
     with open(path, "wb") as f:
         f.write(bytes(buf))
     want = [(r[0], r[1], r[2], r[3], r[4]) for r in recs]
@@ -240,10 +241,11 @@ def test_wal_single_bit_flip_never_silently_decodes(recs, data, tmp_path):
     assert len(decoded) < len(want) or not torn
 
 
-@given(st.lists(wal_record_strategy, min_size=1, max_size=8),
-       st.data())
+@given(recs=st.lists(wal_record_strategy, min_size=1, max_size=8),
+       data=st.data())
 @settings(max_examples=60, deadline=None)
-def test_wal_torn_tail_yields_longest_valid_prefix(recs, data, tmp_path):
+def test_wal_torn_tail_yields_longest_valid_prefix(recs, data,
+                                                   tmp_path_factory):
     """Truncate the log at any byte offset: scan_wal returns exactly the
     records whose complete frames fit in the prefix, flags the tail torn
     iff bytes of an incomplete frame remain, and reports the resume
@@ -252,7 +254,7 @@ def test_wal_torn_tail_yields_longest_valid_prefix(recs, data, tmp_path):
     frames = [encode_record(WalRecord(*r)) for r in recs]
     whole = b"".join(frames)
     cut = data.draw(st.integers(0, len(whole)))
-    path = str(tmp_path / "torn.wal")
+    path = str(tmp_path_factory.mktemp("torn") / "torn.wal")  # per example
     with open(path, "wb") as f:
         f.write(whole[:cut])
     got, torn, valid = scan_wal(path)

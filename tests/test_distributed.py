@@ -28,8 +28,8 @@ def test_moe_distributed_matches_single_device():
         from repro.sharding import MeshRules
         cfg = dataclasses.replace(get_config("kimi_k2_1t").reduced(),
                                   capacity_factor=16.0, moe_sharding="ep")
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_smoke_mesh
+        mesh = make_smoke_mesh((2, 2), ("data", "model"))
         key = jax.random.PRNGKey(0)
         lp = jax.tree.map(lambda x: x[0], M.init_moe(cfg, key, 1))
         x = jax.random.normal(key, (4, 16, cfg.d_model))
@@ -65,8 +65,8 @@ def test_hybrid_attention_distributed_matches_local():
               and kk not in ("pos", "tail_len", "n_blocks")}
         lc.update({kk: cache[kk] for kk in ("n_blocks", "tail_len")})
         local = H.hybrid_attention(cfg, MeshRules(), lc, q, budget=nb)
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_smoke_mesh
+        mesh = make_smoke_mesh((2, 4), ("data", "model"))
         rules = MeshRules(mesh=mesh).with_kv_seq(("data", "model"))
         with mesh:
             dist = jax.jit(lambda lc, q: H.hybrid_attention(
@@ -81,11 +81,10 @@ def test_hybrid_attention_distributed_matches_local():
 def test_compressed_psum_across_pod_axis():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import compressed_psum
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ("pod", "data"))
+        from repro.launch.mesh import make_smoke_mesh
+        mesh = make_smoke_mesh((4, 2), ("pod", "data"))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
         def f(xl):
@@ -93,8 +92,8 @@ def test_compressed_psum_across_pod_axis():
             return s[None], res[None]
 
         with mesh:
-            s, res = shard_map(f, mesh=mesh, in_specs=P("pod"),
-                               out_specs=P("pod"), check_rep=False)(x)
+            s, res = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                   out_specs=P("pod"), check_vma=False)(x)
         true = jnp.sum(x, axis=0)
         err = float(jnp.abs(s[0] - true).max())
         scale = float(jnp.abs(x).max()) / 127.0
@@ -113,8 +112,8 @@ def test_train_step_runs_on_2x2_mesh():
         from repro.models.config import ShapeConfig
         cfg = get_config("qwen3_4b").reduced()
         shape = ShapeConfig("t", 32, 4, "train")
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_smoke_mesh
+        mesh = make_smoke_mesh((2, 2), ("data", "model"))
         rules = make_rules(cfg, shape, mesh)
         step, args, in_sh, out_sh = train_artifacts(cfg, shape, rules,
                                                     n_micro=2)

@@ -167,6 +167,22 @@ def test_straggler_hedge_wins_with_identical_result():
     assert fp.events == ["delay shard 0 by 1.500s"]
 
 
+def test_failing_shard_is_retried_not_hedged():
+    """A shard backing off between failed attempts is the retry loop's,
+    not a straggler: however long the backoff outlasts the other shards,
+    no hedge twin runs it, so the fault's outcome does not hang on
+    timing."""
+    rng = np.random.default_rng(46)
+    store = make_store(rng)
+    ex = sharded(retry_backoff_s=0.3, max_attempts=3)
+    clean, _ = ex.execute_stats(store, GROUPED_Q)
+    with inject(FaultPlan(fail_shard={1: 2})) as fp:
+        rows, stats = ex.execute_stats(store, GROUPED_Q)
+    assert rows == clean
+    assert stats.hedges == 0 and stats.shard_retries == 2
+    assert fp.events == ["fail shard 1 attempt 0", "fail shard 1 attempt 1"]
+
+
 def test_deadline_raises_query_timeout_with_partial_progress():
     rng = np.random.default_rng(45)
     store = make_store(rng)
@@ -403,6 +419,101 @@ def test_fault_matrix_pushdown_device_degrades_to_host():
         assert h[g]["n"] == d[g]["n"]
         np.testing.assert_allclose(d[g]["sv"], h[g]["sv"],
                                    atol=1e-3, rtol=1e-4)
+
+
+class _Unlowerable(Exception):
+    """Stands in for the TPU compiler refusing the kernel."""
+
+
+def _refusing_kernel(*static):
+    import jax
+
+    def kernel(*args, **kw):
+        raise _Unlowerable("infer-vector-layout: unsupported shape cast")
+    return jax.jit(kernel, static_argnames=static)
+
+
+def _faulting_kernel(*static):
+    """Compiles fine; raises on the device while running."""
+    import jax
+
+    def boom(x):
+        raise RuntimeError("device fault")
+
+    def kernel(deltas, *args, **kw):
+        out = jax.pure_callback(boom, jax.ShapeDtypeStruct((), deltas.dtype),
+                                deltas.sum())
+        return out, out, out, out
+    return jax.jit(kernel, static_argnames=static)
+
+
+@pytest.mark.device
+@pytest.mark.parametrize("path", ["pushdown", "collective", "per-shard"])
+def test_kernel_lowering_error_fails_the_query(monkeypatch, path):
+    """A kernel the compiler refuses is a failed query, never a silent host
+    answer: only ``KernelLaunchError`` walks down the ladder."""
+    from repro.kernels import ops
+    store = make_store(np.random.default_rng(74), n=256, block_rows=64,
+                       dml=False)
+    monkeypatch.setattr(ops, "fused_scan_agg",
+                        _refusing_kernel("ndv", "coalesce"))
+    monkeypatch.setattr(ops, "sharded_scan_agg", _refusing_kernel(
+        "ndv", "mesh", "coalesce", "topk"))
+    ex = {"pushdown": PushdownExecutor(device=True),
+          "collective": sharded(device=True, device_route="collective"),
+          "per-shard": sharded(device=True, device_route="host")}[path]
+    with pytest.raises(_Unlowerable):
+        ex.execute_stats(store, DEVICE_Q)
+
+
+@pytest.mark.device
+def test_device_kernel_compiles_once_per_launch_shape(monkeypatch):
+    """Checking compile errors apart from runtime faults costs one compile
+    per launch shape, not one per query: repeat queries reuse it."""
+    from repro.kernels import ops
+    store = make_store(np.random.default_rng(76), n=256, block_rows=64,
+                       dml=False)
+    real, lowered = ops.fused_scan_agg, []
+
+    class Counting:
+        def lower(self, *args, **static):
+            lowered.append(static)
+            return real.lower(*args, **static)
+
+    monkeypatch.setattr(ops, "fused_scan_agg", Counting())
+    ex = PushdownExecutor(device=True)
+    first = ex.execute_stats(store, DEVICE_Q)
+    for _ in range(3):
+        rows, stats = ex.execute_stats(store, DEVICE_Q)
+        assert stats.used_device and rows == first[0]
+    assert len(lowered) == 1
+
+
+@pytest.mark.device
+@pytest.mark.parametrize("path,first", [
+    ("pushdown", "device->host-pushdown"),
+    ("collective", "device-collective->per-shard-device"),
+    ("per-shard", "per-shard-device->host-pushdown")])
+def test_kernel_runtime_fault_degrades_as_launch_error(monkeypatch, path,
+                                                       first):
+    """A fault raised while a compiled kernel runs is wrapped as
+    ``KernelLaunchError`` and degrades to the host scan, like an injected
+    launch fault — on every device route."""
+    from repro.kernels import ops
+    store = make_store(np.random.default_rng(75), n=256, block_rows=64,
+                       dml=False)
+    clean = PushdownExecutor().execute(store, DEVICE_Q)
+    monkeypatch.setattr(ops, "fused_scan_agg",
+                        _faulting_kernel("ndv", "coalesce"))
+    monkeypatch.setattr(ops, "sharded_scan_agg", _faulting_kernel(
+        "ndv", "mesh", "coalesce", "topk"))
+    ex = {"pushdown": PushdownExecutor(device=True),
+          "collective": sharded(device=True, device_route="collective"),
+          "per-shard": sharded(device=True, device_route="host")}[path]
+    rows, stats = ex.execute_stats(store, DEVICE_Q)
+    assert not stats.used_device
+    assert stats.degraded[0].startswith(f"{first}: KernelLaunchError")
+    assert norm(rows) == norm(clean)
 
 
 # ---------------------------------------------------------------------------

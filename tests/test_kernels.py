@@ -177,8 +177,11 @@ def test_columnar_scan_kernel(nb, rows):
                                            atol=1e-4, rtol=1e-5)
 
 
-@pytest.mark.parametrize("nb,rows,ndv", [(4, 128, 8), (8, 256, 16),
-                                         (1, 128, 130)])
+@pytest.mark.parametrize("nb,rows,ndv", [
+    (4, 128, 8), (8, 256, 16), (1, 128, 130),
+    (2, 1024, 200),        # G·Bk > ONEHOT_ELEMS: two row chunks per block
+    (2, 1536, 200),        # three chunks, the partial block ends mid-chunk
+    (3, 200, 8)])          # Bk not a multiple of 128: lane padding
 def test_fused_scan_agg_kernel_vs_oracle(nb, rows, ndv):
     ks = keys(5)
     deltas = jax.random.randint(ks[0], (nb, rows), 0, 50, jnp.int32)
@@ -434,3 +437,12 @@ def test_dict_groupby_kernel(N, ndv):
     wsums, wcounts = ref.ref_dict_groupby(codes, vals, ndv)
     np.testing.assert_allclose(sums, wsums, atol=1e-3, rtol=1e-4)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(wcounts))
+
+
+def test_kernel_dispatch_refuses_backends_without_a_kernel_path(monkeypatch):
+    """Interpret mode is for the CPU only: any other non-TPU backend is an
+    error, never a silent interpreter."""
+    from repro.kernels.ops import _on_tpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no Pallas TPU kernel path"):
+        _on_tpu()
