@@ -28,6 +28,7 @@ import numpy as np
 
 from .encoding import (EncodedColumn, choose_encoding, payload_checksum,
                        shrink_bytes)
+from . import spans
 from .errors import BlockCorruption
 from .replica import collect as _collect_repairs, event_mark as _repair_mark
 from .relation import And, Column, ColType, PredOp, Predicate, Schema, Table
@@ -366,6 +367,9 @@ class ScanStats:
                                        # tile chunks, partials merged)
     device_route: str = ""             # 'collective' | 'host' when used_device
     n_devices: int = 0                 # scan-mesh size the device fan-out saw
+    h2d_bytes: int = 0                 # host argument bytes handed to the
+                                       # device, over every launch, chunk
+                                       # and retry (0 for device arrays)
     topk_pushdown: bool = False        # per-shard limit-aware top-k ran
     # --- fault-tolerance provenance ------------------------------------
     degraded: List[str] = dataclasses.field(default_factory=list)
@@ -494,7 +498,7 @@ class LSMStore:
         return self.baseline.row(i) if i >= 0 else None
 
     def insert(self, row: Dict[str, Any]) -> int:
-        with self._lock:
+        with spans.span("ob.write"), self._lock:
             pk = row[self.schema.pk]
             ts = self._next_ts_locked()
             if self._old_row(pk, ts) is not None:
@@ -519,7 +523,7 @@ class LSMStore:
             return ts
 
     def delete(self, pk: Any) -> int:
-        with self._lock:
+        with spans.span("ob.write"), self._lock:
             ts = self._next_ts_locked()
             old = self._old_row(pk, ts)
             if old is None:

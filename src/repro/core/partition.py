@@ -62,6 +62,7 @@ from . import cost
 from . import faultinject
 from . import pushdown as _pd
 from . import replica as _replica
+from . import spans
 from .engine import (Query, VectorEngine, _item, null_aware_key_codes,
                      null_last_key, pack_sort_keys)
 from .errors import (BlockCorruption, Deadline, KernelLaunchError,
@@ -558,14 +559,15 @@ class ShardedScanExecutor:
         str_aggs = any(store.schema.spec(a.column).ctype == ColType.STR
                        for a in q.aggs if a.column)
         try:
-            if q.aggs and not str_aggs:
-                rows = self._execute_partials(store, q, needed, shards,
-                                              verdicts, over, inc_rows, stats,
-                                              coalesce, deadline)
-            else:
-                rows = self._execute_gather(store, q, needed, shards,
-                                            verdicts, over, inc_rows, stats,
-                                            coalesce, deadline)
+            with spans.span("ob.host_scan"):
+                if q.aggs and not str_aggs:
+                    rows = self._execute_partials(
+                        store, q, needed, shards, verdicts, over, inc_rows,
+                        stats, coalesce, deadline)
+                else:
+                    rows = self._execute_gather(
+                        store, q, needed, shards, verdicts, over, inc_rows,
+                        stats, coalesce, deadline)
         except (QueryTimeout, BlockCorruption):
             raise                   # deterministic: retrying cannot help
         # lint: allow(broad-except) — degradation-ladder rung: any
@@ -1007,8 +1009,8 @@ class ShardedScanExecutor:
         stats.topk_pushdown = k is not None
         out = _pd.run_device_kernel(
             "collective", ops.sharded_scan_agg, deltas, bases, counts,
-            plan.lo, plan.hi, codes, values, bmask, ndv=stage.ndv, mesh=mesh,
-            coalesce=tile, topk=k or 0)
+            plan.lo, plan.hi, codes, values, bmask, stats=stats,
+            ndv=stage.ndv, mesh=mesh, coalesce=tile, topk=k or 0)
         if k is not None:
             g_ids, g_cnt, g_sums, g_mins, g_maxs, total = out
             stats.actual_rows = int(total)
@@ -1057,26 +1059,27 @@ def stack_device_stage(stage, shards: Sequence[BlockShard],
     tile would break the kernel's valid-rows-prefix invariant.  Shared by
     ``ShardedScanExecutor._device_collective`` and the route benchmark."""
     from ..launch.mesh import scan_launch_shape
-    _, S = scan_launch_shape(len(shards), mesh)
-    nbp = max(s.n_blocks for s in shards)
-    bk = stage.deltas.shape[1]
-    K, V = stage.codes.shape[1], stage.values.shape[1]
-    out = (np.zeros((S, nbp, bk), np.int32),
-           np.zeros((S, nbp), np.int32),
-           np.zeros((S, nbp), np.int32),
-           np.zeros((S, nbp, K, bk), np.int32),
-           np.zeros((S, nbp, V, bk), np.float32),
-           np.zeros((S, nbp), bool))
-    srcs = (stage.deltas, stage.bases, stage.counts, stage.codes,
-            stage.values, block_mask)
-    for i, s in enumerate(shards):
-        sl = slice(s.lo_block, s.hi_block)
-        for dst, src in zip(out, srcs):
-            dst[i, : s.n_blocks] = src[sl]
-    tile = max(int(tile), 1)
-    while nbp % tile:
-        tile -= 1
-    return out, tile
+    with spans.span("ob.stack"):
+        _, S = scan_launch_shape(len(shards), mesh)
+        nbp = max(s.n_blocks for s in shards)
+        bk = stage.deltas.shape[1]
+        K, V = stage.codes.shape[1], stage.values.shape[1]
+        out = (np.zeros((S, nbp, bk), np.int32),
+               np.zeros((S, nbp), np.int32),
+               np.zeros((S, nbp), np.int32),
+               np.zeros((S, nbp, K, bk), np.int32),
+               np.zeros((S, nbp, V, bk), np.float32),
+               np.zeros((S, nbp), bool))
+        srcs = (stage.deltas, stage.bases, stage.counts, stage.codes,
+                stage.values, block_mask)
+        for i, s in enumerate(shards):
+            sl = slice(s.lo_block, s.hi_block)
+            for dst, src in zip(out, srcs):
+                dst[i, : s.n_blocks] = src[sl]
+        tile = max(int(tile), 1)
+        while nbp % tile:
+            tile -= 1
+        return out, tile
 
 
 def launch_shard_kernels(plan, stage, shards: Sequence[BlockShard],
@@ -1112,7 +1115,8 @@ def launch_shard_kernels(plan, stage, shards: Sequence[BlockShard],
             deadline.check(stats, completed=len(outs), total=len(shards))
         if fp is not None:
             fp.on_kernel_launch("host")
-        outs.append(_pd.dispatch_device_kernel("host", exe, *ins))
+        outs.append(_pd.dispatch_device_kernel("host", exe, *ins,
+                                               stats=stats))
     return _pd.await_device_kernels("host", outs)
 
 

@@ -61,6 +61,7 @@ import numpy as np
 
 from . import cost
 from . import faultinject
+from . import spans
 from .encoding import DeltaFOREncoded, DictEncoded, PlainEncoded
 from .engine import Query, VectorEngine, _item
 from .errors import (BlockCorruption, Deadline, KernelLaunchError,
@@ -150,17 +151,19 @@ def scan_preamble(store: LSMStore, q: Query, ts: int, stats: ScanStats,
     inside merge-on-read assembly too.  Returns (needed columns,
     overridden row ids, live incremental rows, per-block verdicts)."""
     base = store.baseline
-    needed = sorted(VectorEngine.columns_needed(q, store.schema.names))
-    inc = store._incremental_effective(ts)
-    stats.rows_merged_incremental = len(inc)
-    if deadline is not None:
-        deadline.check(stats)
-    over = np.asarray(sorted(i for i in (base.locate(pk) for pk in inc)
-                             if i >= 0), np.int64)
-    inc_rows = store.live_incremental_rows(inc, q.preds, deadline=deadline)
-    stats.blocks_total = base.n_blocks
-    verdicts = cost.prune_verdicts(store, q.preds)
-    return needed, over, inc_rows, verdicts
+    with spans.span("ob.preamble"):
+        needed = sorted(VectorEngine.columns_needed(q, store.schema.names))
+        inc = store._incremental_effective(ts)
+        stats.rows_merged_incremental = len(inc)
+        if deadline is not None:
+            deadline.check(stats)
+        over = np.asarray(sorted(i for i in (base.locate(pk) for pk in inc)
+                                 if i >= 0), np.int64)
+        inc_rows = store.live_incremental_rows(inc, q.preds,
+                                               deadline=deadline)
+        stats.blocks_total = base.n_blocks
+        verdicts = cost.prune_verdicts(store, q.preds)
+        return needed, over, inc_rows, verdicts
 
 
 def assemble_columns(store: LSMStore, needed: Sequence[str],
@@ -406,29 +409,33 @@ class PushdownExecutor:
                     cost.observe_scan(store, est, stats.actual_rows)
                 return out, stats
 
-        # flat group-less aggregates can swallow clean blocks from sketches
-        sketch = _SketchAgg(q) if (q.aggs and not q.group_by) else None
+        with spans.span("ob.host_scan"):
+            # flat group-less aggregates can swallow clean blocks from
+            # sketches
+            sketch = _SketchAgg(q) if (q.aggs and not q.group_by) else None
 
-        # -- stage 2: encoded-domain filter ------------------------------
-        filtered = filter_blocks(store, q, needed, verdicts, over,
-                                 range(nb), stats, sketch, coalesce,
-                                 sub_block=adaptive, deadline=deadline)
-        stats.actual_rows = (sum(fb.n_selected for fb in filtered)
-                             + (sketch.n_rows if sketch is not None else 0))
-        stats.estimate = est
-        if self.observe:
-            cost.observe_scan(store, est, stats.actual_rows)
+            # -- stage 2: encoded-domain filter --------------------------
+            filtered = filter_blocks(store, q, needed, verdicts, over,
+                                     range(nb), stats, sketch, coalesce,
+                                     sub_block=adaptive, deadline=deadline)
+            stats.actual_rows = (
+                sum(fb.n_selected for fb in filtered)
+                + (sketch.n_rows if sketch is not None else 0))
+            stats.estimate = est
+            if self.observe:
+                cost.observe_scan(store, est, stats.actual_rows)
 
-        # -- stage 3+4: late materialization + terminal operators --------
-        if sketch is not None:
-            return self._finish_flat(q, sketch, filtered, inc_rows, store), stats
-        cols, masks = self._materialize(store, needed, filtered, inc_rows,
-                                        with_nulls=True)
-        n_rows = sum(fb.n_selected for fb in filtered) + len(inc_rows)
-        out = self.engine.finalize(q, lambda nm: cols[nm], n_rows,
-                                   store.schema.names,
-                                   nulls=lambda nm: masks[nm])
-        return out, stats
+            # -- stage 3+4: late materialization + terminal operators ----
+            if sketch is not None:
+                return self._finish_flat(q, sketch, filtered, inc_rows,
+                                         store), stats
+            cols, masks = self._materialize(store, needed, filtered, inc_rows,
+                                            with_nulls=True)
+            n_rows = sum(fb.n_selected for fb in filtered) + len(inc_rows)
+            out = self.engine.finalize(q, lambda nm: cols[nm], n_rows,
+                                       store.schema.names,
+                                       nulls=lambda nm: masks[nm])
+            return out, stats
 
     # ------------------------------------------------- late materialization
     @staticmethod
@@ -600,7 +607,7 @@ class PushdownExecutor:
             return run_device_kernel(
                 "pushdown", ops.fused_scan_agg, stage.deltas, stage.bases,
                 stage.counts, plan.lo, plan.hi, stage.codes, stage.values,
-                mask, ndv=stage.ndv, coalesce=tile)
+                mask, stats=stats, ndv=stage.ndv, coalesce=tile)
 
         try:
             if deadline is not None and nblocks > chunk:
@@ -702,19 +709,39 @@ def compile_device_kernel(kernel, *args, **static):
            tuple(_arg_key(a) for a in args))
     exe = _COMPILED.get(key)
     if exe is None:
-        exe = kernel.lower(*args, **static).compile()
+        with spans.span("ob.kernel_compile"):
+            exe = kernel.lower(*args, **static).compile()
         if len(_COMPILED) >= _COMPILED_MAX:
             _COMPILED.clear()
         _COMPILED[key] = exe
     return exe
 
 
-def dispatch_device_kernel(route: str, exe, *args):
-    """Dispatch a compiled launch without waiting for it; a runtime fault
-    raised at dispatch is wrapped as ``KernelLaunchError``."""
+def host_bytes(args) -> int:
+    """Bytes of the host arguments a launch copies to the device: numpy
+    arrays and Python scalars at the dtype JAX gives them.  Arguments
+    already on the device count 0."""
     import jax
+    n = 0
+    for a in args:
+        if not isinstance(a, jax.Array):
+            dt = np.dtype(jax.dtypes.canonicalize_dtype(np.result_type(a)))
+            n += int(np.size(a)) * dt.itemsize
+    return n
+
+
+def dispatch_device_kernel(route: str, exe, *args,
+                           stats: Optional[ScanStats] = None):
+    """Dispatch a compiled launch without waiting for it.  Host arguments
+    are handed to the device here; their bytes add to ``stats.h2d_bytes``.
+    A runtime fault raised at dispatch is wrapped as
+    ``KernelLaunchError``."""
+    import jax
+    if stats is not None:
+        stats.h2d_bytes += host_bytes(args)
     try:
-        return exe(*args)
+        with spans.span("ob.dispatch"):
+            return exe(*args)
     except jax.errors.JaxRuntimeError as e:
         raise KernelLaunchError(route, e) from e
 
@@ -725,17 +752,20 @@ def await_device_kernels(route: str, outs):
     degrade on."""
     import jax
     try:
-        return jax.block_until_ready(outs)
+        with spans.span("ob.wait"):
+            return jax.block_until_ready(outs)
     except jax.errors.JaxRuntimeError as e:
         raise KernelLaunchError(route, e) from e
 
 
-def run_device_kernel(route: str, kernel, *args, **static):
+def run_device_kernel(route: str, kernel, *args,
+                      stats: Optional[ScanStats] = None, **static):
     """Compile (once per launch shape), run and wait for one device launch:
-    compile errors propagate, runtime faults raise ``KernelLaunchError``."""
+    compile errors propagate, runtime faults raise ``KernelLaunchError``.
+    The launch's host bytes add to ``stats.h2d_bytes``."""
     exe = compile_device_kernel(kernel, *args, **static)
-    return await_device_kernels(route,
-                                dispatch_device_kernel(route, exe, *args))
+    return await_device_kernels(
+        route, dispatch_device_kernel(route, exe, *args, stats=stats))
 
 
 def plan_device(store: LSMStore, q: Query) -> Optional[DevicePlan]:
@@ -821,9 +851,15 @@ def stage_device(store: LSMStore, plan: DevicePlan) -> Optional[DeviceStage]:
     """Build the [Nb, ...] kernel inputs: FOR offsets of the predicate
     column (zeros when predicate-less), per-key global group codes, f32
     value planes.  None when the packed group domain is too large."""
+    with spans.span("ob.stage"):
+        return _stage(store, plan)
+
+
+def _stage(store: LSMStore, plan: DevicePlan) -> Optional[DeviceStage]:
     base = store.baseline
     nb, bk = base.n_blocks, base.block_rows
-    gdicts = [_global_dict(base, g) for g in plan.group_cols]
+    with spans.span("ob.stage.dicts"):
+        gdicts = [_global_dict(base, g) for g in plan.group_cols]
     # NULL group keys: a key column whose baseline carries NULLs gets one
     # reserved sentinel slot (code == len(gdict), the largest code) in its
     # packed domain; ``emit_device_groups`` decodes it back to None.
@@ -843,36 +879,37 @@ def stage_device(store: LSMStore, plan: DevicePlan) -> Optional[DeviceStage]:
     codes = np.zeros((nb, len(plan.group_cols), bk), np.int32)
     values = np.zeros((nb, n_vals, bk), np.float32)
     remaps = [{} for _ in plan.group_cols]     # block dict id -> global codes
-    for b in range(nb):
-        blo, bhi = base.block_bounds(b)
-        n = bhi - blo
-        counts[b] = n
-        if plan.pred_col is not None:
-            cst = base.cols[plan.pred_col]
-            cst.verify_block(b)        # raw payload access skips decode_block
-            enc = cst.blocks[b]
-            if isinstance(enc, DeltaFOREncoded):   # already in offset domain
-                deltas[b, :n] = enc.deltas
-                bases[b] = enc.base
-            else:
-                deltas[b, :n] = enc.decode()
-        for k, g in enumerate(plan.group_cols):
-            base.cols[g].verify_block(b)
-            genc = base.cols[g].blocks[b]
-            if isinstance(genc, DictEncoded):      # map codes, never decode
-                remap = remaps[k].get(id(genc))
-                if remap is None:
-                    remap = np.searchsorted(gdicts[k], genc.dictionary)
-                    remaps[k][id(genc)] = remap
-                codes[b, k, :n] = remap[genc.codes]
-            else:
-                codes[b, k, :n] = np.searchsorted(gdicts[k], genc.decode())
-            if key_nulls[k]:
-                nmask = base.cols[g].block_nulls(b)
-                if nmask is not None:              # NULL rows → sentinel
-                    codes[b, k, :n][nmask] = gdicts[k].shape[0]
-        for v, c in enumerate(plan.value_cols):
-            values[b, v, :n] = base.cols[c].decode_block(b)
+    with spans.span("ob.stage.blocks"):
+        for b in range(nb):
+            blo, bhi = base.block_bounds(b)
+            n = bhi - blo
+            counts[b] = n
+            if plan.pred_col is not None:
+                cst = base.cols[plan.pred_col]
+                cst.verify_block(b)    # raw payload access skips decode_block
+                enc = cst.blocks[b]
+                if isinstance(enc, DeltaFOREncoded):   # in the offset domain
+                    deltas[b, :n] = enc.deltas
+                    bases[b] = enc.base
+                else:
+                    deltas[b, :n] = enc.decode()
+            for k, g in enumerate(plan.group_cols):
+                base.cols[g].verify_block(b)
+                genc = base.cols[g].blocks[b]
+                if isinstance(genc, DictEncoded):  # map codes, never decode
+                    remap = remaps[k].get(id(genc))
+                    if remap is None:
+                        remap = np.searchsorted(gdicts[k], genc.dictionary)
+                        remaps[k][id(genc)] = remap
+                    codes[b, k, :n] = remap[genc.codes]
+                else:
+                    codes[b, k, :n] = np.searchsorted(gdicts[k], genc.decode())
+                if key_nulls[k]:
+                    nmask = base.cols[g].block_nulls(b)
+                    if nmask is not None:              # NULL rows → sentinel
+                        codes[b, k, :n][nmask] = gdicts[k].shape[0]
+            for v, c in enumerate(plan.value_cols):
+                values[b, v, :n] = base.cols[c].decode_block(b)
     return DeviceStage(deltas, bases, counts, codes, values, gdicts, ndv)
 
 
@@ -887,40 +924,41 @@ def emit_device_groups(q: Query, plan: DevicePlan, stage: DeviceStage,
     the accumulators are already top-k-sliced on device: position ``j``
     holds packed group ``group_ids[j]`` (zero-count slots are padding from
     a result smaller than k)."""
-    strides = []
-    acc = 1
-    for d in reversed(stage.ndv):
-        strides.append(acc)
-        acc *= d
-    strides = list(reversed(strides))
-    vidx = {c: v for v, c in enumerate(plan.value_cols)}
-    out: List[Dict[str, Any]] = []
-    cols_live = np.nonzero(g_cnt)[0]
-    packed = cols_live if group_ids is None else group_ids[cols_live]
-    for j, g in zip(cols_live, packed):
-        r: Dict[str, Any] = {}
-        for k, col in enumerate(plan.group_cols):
-            di = (g // strides[k]) % stage.ndv[k]
-            # the reserved sentinel slot (>= dictionary size) is NULL
-            r[col] = (None if di >= stage.gdicts[k].shape[0]
-                      else _item(stage.gdicts[k][di]))
-        n = int(g_cnt[j])
-        for a in q.aggs:
-            if a.op == "count":
-                r[a.alias] = n
-                continue
-            v = vidx[a.column]
-            if a.op == "sum":
-                r[a.alias] = float(g_sums[v, j])
-            elif a.op == "avg":
-                r[a.alias] = float(g_sums[v, j]) / n
-            elif a.op == "min":
-                r[a.alias] = float(g_mins[v, j])
-            elif a.op == "max":
-                r[a.alias] = float(g_maxs[v, j])
-        out.append(r)
-    if q.sort_by:
-        out = VectorEngine._sort(out, q.sort_by)
-    if q.limit is not None:
-        out = out[: q.limit]
-    return out
+    with spans.span("ob.emit"):
+        strides = []
+        acc = 1
+        for d in reversed(stage.ndv):
+            strides.append(acc)
+            acc *= d
+        strides = list(reversed(strides))
+        vidx = {c: v for v, c in enumerate(plan.value_cols)}
+        out: List[Dict[str, Any]] = []
+        cols_live = np.nonzero(g_cnt)[0]
+        packed = cols_live if group_ids is None else group_ids[cols_live]
+        for j, g in zip(cols_live, packed):
+            r: Dict[str, Any] = {}
+            for k, col in enumerate(plan.group_cols):
+                di = (g // strides[k]) % stage.ndv[k]
+                # the reserved sentinel slot (>= dictionary size) is NULL
+                r[col] = (None if di >= stage.gdicts[k].shape[0]
+                          else _item(stage.gdicts[k][di]))
+            n = int(g_cnt[j])
+            for a in q.aggs:
+                if a.op == "count":
+                    r[a.alias] = n
+                    continue
+                v = vidx[a.column]
+                if a.op == "sum":
+                    r[a.alias] = float(g_sums[v, j])
+                elif a.op == "avg":
+                    r[a.alias] = float(g_sums[v, j]) / n
+                elif a.op == "min":
+                    r[a.alias] = float(g_mins[v, j])
+                elif a.op == "max":
+                    r[a.alias] = float(g_maxs[v, j])
+            out.append(r)
+        if q.sort_by:
+            out = VectorEngine._sort(out, q.sort_by)
+        if q.limit is not None:
+            out = out[: q.limit]
+        return out

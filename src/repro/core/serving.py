@@ -44,7 +44,7 @@ from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
-from . import cost, replica
+from . import cost, replica, spans
 from .engine import Query
 from .errors import ServerClosed
 from .session import CompiledPlan, Database, ResultSet
@@ -82,6 +82,7 @@ class Ticket:
         self.tenant = tenant
         self.seq = seq
         self.submitted = time.monotonic()
+        self.picked_at: Optional[float] = None     # popped by the scheduler
         self.dispatched_at: Optional[float] = None
         self.done_at: Optional[float] = None
         self.cache_hit = False
@@ -343,8 +344,10 @@ class QueryServer:
                     admitted_since_scrub = 0
                     self._scrub("idle")
                 continue
+            ticket.picked_at = time.monotonic()
             try:
-                self._admit(ticket)
+                with spans.request(ticket.seq), spans.span("ob.admit"):
+                    self._admit(ticket)
             # lint: allow(broad-except) — scheduler boundary: *any*
             # compile-time failure must resolve the ticket (the submitter
             # is blocked in result()), never kill the scheduler thread
@@ -388,7 +391,8 @@ class QueryServer:
         """Scheduler-thread admission: compile, then answer from the
         result cache, attach to an in-flight twin, defer on quota, or
         dispatch to the worker pool."""
-        cplan = self._compile(t)
+        with spans.span("ob.plan"):
+            cplan = self._compile(t)
         rkey = cplan.result_key
         with self._mu:
             hit = self._result_cache.get(rkey)
@@ -431,8 +435,9 @@ class QueryServer:
         result: Optional[ResultSet] = None
         exc: Optional[BaseException] = None
         try:
-            result = self.db.execute(cplan, deadline_s=t._deadline_s)
-            self.db.commit(result)
+            with spans.request(t.seq):
+                result = self.db.execute(cplan, deadline_s=t._deadline_s)
+                self.db.commit(result)
         # lint: allow(broad-except) — worker boundary: the leader and its
         # coalesced followers must resolve no matter what escaped the
         # typed layers below; the exception is re-delivered via result()

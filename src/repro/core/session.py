@@ -61,7 +61,7 @@ import numpy as np
 
 import time
 
-from . import cost
+from . import cost, spans
 from .engine import QAgg, Query, ScalarEngine, VectorEngine
 from .errors import QueryTimeout
 from .health import HealthRegistry
@@ -850,6 +850,11 @@ class Database:
         breaker cools.  A major compaction racing the run swaps the
         baseline mid-scan; that is detected by the baseline-generation
         bump and the run is retried (bounded) against the new baseline."""
+        with spans.span("ob.execute"):
+            return self._execute(cplan, deadline_s)
+
+    def _execute(self, cplan: CompiledPlan,
+                 deadline_s: Optional[float]) -> ResultSet:
         h = self.table(cplan.table)
         store = h.store
         for attempt in range(3):

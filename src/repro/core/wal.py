@@ -50,7 +50,7 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from . import faultinject
+from . import faultinject, spans
 from .errors import RecoveryError
 
 #: Frame magic: marks the start of every record.
@@ -226,22 +226,23 @@ class WriteAheadLog:
     def _flush_locked(self) -> None:
         if not self._pending:
             return
-        if len(self._pending) == 1:
-            buf = _frame(*self._pending[0])
-        else:
-            # one frame per group-commit batch: a single pickle + crc32 +
-            # write amortizes the framing to well under a microsecond per
-            # record, which is what makes the serving path's batched WAL
-            # nearly free on the clean path
-            buf = _frame_payload(list(self._pending))
-        # the append descriptor stays open across flushes (reopening per
-        # statement at group_commit=1 would dominate the clean-path cost);
-        # compact() closes it around the atomic rewrite
-        if self._fd is None:
-            self._fd = os.open(self.path,
-                               os.O_WRONLY | os.O_CREAT | os.O_APPEND, 0o644)
-        os.write(self._fd, buf)
-        self._pending.clear()
+        with spans.span("ob.wal_flush"):
+            if len(self._pending) == 1:
+                buf = _frame(*self._pending[0])
+            else:
+                # one frame per group-commit batch: a single pickle + crc32 +
+                # write amortizes the framing to well under a microsecond per
+                # record, which is what makes the serving path's batched WAL
+                # nearly free on the clean path
+                buf = _frame_payload(list(self._pending))
+            # the append descriptor stays open across flushes (reopening per
+            # statement at group_commit=1 would dominate the clean-path cost);
+            # compact() closes it around the atomic rewrite
+            if self._fd is None:
+                self._fd = os.open(
+                    self.path, os.O_WRONLY | os.O_CREAT | os.O_APPEND, 0o644)
+            os.write(self._fd, buf)
+            self._pending.clear()
 
     def pending(self) -> int:
         with self._lock:
