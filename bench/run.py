@@ -19,8 +19,10 @@ Phases, all in this process (a chip belongs to one process):
    end-to-end ones with the profiler off.
 4. check: writes read back from the program, the program freed, then every
    answer compared with the plain reference at its snapshot
-   (``bench/reference.py``).  Each number compared is printed beside its
-   limit as the last lines on standard error and, last, in the result.
+   (``bench/reference.py``), and the check's seconds, answers, distinct
+   answers and those the reference's cube served logged.  Each number
+   compared is printed beside its limit as the last lines on standard error
+   and, last, in the result.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (with ``breakdown`` in a
@@ -132,6 +134,7 @@ def check(cell: spec.Cell, seed: int, answers: List[Any],
           log: Callable[[str], None]):
     """Compare every answer with the reference at its snapshot; returns the
     numbers compared and the reference tables (for the kernel work)."""
+    t0 = time.monotonic()
     refs: Dict[str, reference.RefTable] = {}
     for k, t in enumerate(cell.config["tables"]):
         refs[t["name"]] = reference.RefTable(deploy.generate(t, seed, k))
@@ -139,24 +142,27 @@ def check(cell: spec.Cell, seed: int, answers: List[Any],
         if a.error is None:
             refs[a.table].inserts += a.inserts
             refs[a.table].deletes += a.deletes
-    worst, wrong, missing = 0.0, 0, 0
+    worst, wrong, missing, cubed = 0.0, 0, 0, 0
     memo: Dict[Any, Any] = {}
     for item, rows, ts in answers:
         if rows is None:
             missing += 1
             continue
         ref = refs[item.table]
-        seen = sum(1 for t, _ in ref.inserts if t <= ts) + \
-            sum(1 for t, _ in ref.deletes if t <= ts)
-        key = (item.table, item.ref, seen)
+        key = (item.table, item.ref, ref.seen(ts))
         if key not in memo:
-            memo[key] = ref.answer(item.ref, ts)
+            want = ref.from_cube(item.ref, ts)
+            cubed += want is not None
+            memo[key] = ref.direct(item.ref, ts) if want is None else want
         diff, err = reference.compare(item.ref, rows, memo[key])
         if diff is not None:
             wrong += 1
             log(f"[check] wrong answer #{item.index} {item.cls} "
                 f"tenant={item.tenant}: {diff}")
         worst = max(worst, err)
+    log(f"[check] seconds={time.monotonic() - t0} "
+        f"answers={len(answers) - missing} distinct={len(memo)} "
+        f"cube={cubed}")
     numbers = {"max_rel_err": worst, "wrong_answers": wrong,
                "unanswered": missing, "lost_writes": lost}
     return numbers, refs
