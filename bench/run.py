@@ -253,7 +253,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             win = window.run(srv, dep, sched, refreshes, seconds, spans)
         in_window = clock.count - c0
         if traced:
+            t2 = time.monotonic()
             jax.profiler.stop_trace()
+            stop_s = time.monotonic() - t2
     finally:
         spans.uninstall()
         srv.close()
@@ -288,16 +290,27 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     out: Dict[str, Any] = {}
     device = dict(info, memory_peak_bytes=mem)
     if traced:
+        # one line, written as each phase ends, so that a run cut after
+        # its window shows the phase it was in
+        def part(text: str, end: str = " ") -> None:
+            print(text, end=end, flush=True)
+
+        part(f"[trace] stop_s={stop_s}")
         path = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
                          recursive=True)[0]
-        rec.trace = tracemod.reduce(path)
+        t = rec.trace = tracemod.reduce(path)
+        part(f"bytes={os.path.getsize(path)} parse_s={t.parse_s} "
+             f"walk_s={t.walk_s} name_s={t.name_s} events={t.events} "
+             f"gaps={t.gaps} spans={t.spans}")
         shutil.rmtree(tdir, ignore_errors=True)
+        t3 = time.monotonic()
         rec.launches = kernel_launches(answers, flags, refs, cell, peak)
-        device["busy_s"] = rec.trace.busy_s
-        device["window_s"] = rec.trace.window_s
+        device["busy_s"] = t.busy_s
+        device["window_s"] = t.window_s
         bounds = sorted({w_["bound"] for w_ in rec.launches})
-        log(f"[trace] kernel_events={rec.trace.kernel_n} "
-            f"device_answers={len(rec.launches)} roofline_bound={bounds}")
+        part(f"launches_s={time.monotonic() - t3} kernel_events={t.kernel_n} "
+             f"device_answers={len(rec.launches)} roofline_bound={bounds}",
+             end="\n")
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         v = spec.metric_reader(cell.bench_dir, m["name"])(rec)

@@ -61,12 +61,13 @@ def idle_gaps(path: str) -> List[List[Any]]:
                       if ev.name.startswith(PREFIXES)]
     w0, w1 = next((s, e) for n, s, e in spans if n == trace.WINDOW_SPAN)
     inner = [sp for sp in spans if sp[0] != trace.WINDOW_SPAN]
-    gaps = []
+    idle = []
     for iv in chips:
         merged = trace._union(trace._clip(iv, w0, w1))
         edges = [w0] + [x for m in merged for x in m] + [w1]
-        gaps += [((b - a) * 1e-9, trace._name_gap(a, b, inner))
-                 for a, b in zip(edges[::2], edges[1::2]) if b > a]
+        idle += [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
+    gaps = [((b - a) * 1e-9, n)
+            for (a, b), n in zip(idle, trace.name_gaps(idle, inner))]
     gaps.sort(key=lambda g: -g[0])
     return [[n, s] for s, n in gaps[:trace.TOP]]
 
