@@ -275,7 +275,7 @@ def collective_vs_host(n: int = COLL_N, block_rows: int = COLL_BLOCK_ROWS,
                                       launch_shard_kernels, range_partition,
                                       stack_device_stage, tree_reduce)
     from repro.kernels import ops
-    from repro.launch.mesh import make_scan_mesh, scan_shard_devices
+    from repro.core.placement import make_scan_mesh, scan_shard_devices
     if store is None:
         store = make_store(np.random.default_rng(7), n, block_rows)
     q = _query()

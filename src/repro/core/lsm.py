@@ -367,6 +367,14 @@ class ScanStats:
                                        # tile chunks, partials merged)
     device_route: str = ""             # 'collective' | 'host' when used_device
     n_devices: int = 0                 # scan-mesh size the device fan-out saw
+    chip_blocks: int = 0               # most unpruned baseline blocks any
+                                       # one scan device held for the answer
+    pad_blocks: int = 0                # zero-count blocks the collective
+                                       # launch added to even out its shards
+    collective_bytes: int = 0          # group-partial bytes the chips put
+                                       # into the cross-chip reduce: groups
+                                       # x (count + V sums, mins, maxs) x
+                                       # 4 B x D (0 on one device)
     h2d_bytes: int = 0                 # host argument bytes handed to the
                                        # device, over every launch, chunk
                                        # and retry (0 for device arrays)

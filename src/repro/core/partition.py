@@ -4,9 +4,8 @@ The paper's Mercury deployment answers petabyte-scale analytical queries by
 fanning one scan out across data replicas and tree-merging partial
 aggregates; this module is that layer over the local storage model.  A
 ``VirtualSSTable``'s encoded baseline blocks are **range-partitioned** into
-contiguous shards — boundaries are chosen from the ``SkippingIndex`` leaf
-sketches (per-block row counts), so shards carry near-equal row weight and,
-because baseline blocks are pk-ordered, each shard is a pk range.  Every
+contiguous pk-range shards of near-equal row weight (``core/placement.py``,
+which also fixes each block's chip on a multi-device scan mesh).  Every
 shard then runs the same pushdown pipeline the single-shard executor uses
 (zone-map prune → encoded-domain filter → late materialization) via
 ``pushdown.filter_blocks``, producing a ``GroupedPartial`` of
@@ -34,9 +33,10 @@ launch to ``kernels.fused_scan_agg.sharded_scan_agg`` — the fused kernel
 runs per shard on its 'scan'-mesh device and the count/sum/min/max partials
 tree-reduce on device via psum/pmin/pmax over packed group-code
 accumulators, so no ``GroupedPartial`` ever crosses back to the host; the
-**host** route keeps the legacy per-shard kernel launches (round-robin
-placement via ``launch.mesh.scan_shard_devices``) with a host-side
-tree-merge.
+**host** route keeps the legacy per-shard kernel launches (placed by
+``placement.scan_shard_devices``) with a host-side tree-merge.  On a mesh
+of D > 1 devices either route runs every answer on all D chips, each over
+the baseline blocks its chip partition holds (``placement.place_shards``).
 
 ``Query(sort_by=<group columns>, limit=k)`` additionally activates
 **limit-aware top-k pushdown**: because a group's sort rank is fixed by its
@@ -67,7 +67,10 @@ from .engine import (Query, VectorEngine, _item, null_aware_key_codes,
                      null_last_key, pack_sort_keys)
 from .errors import (BlockCorruption, Deadline, KernelLaunchError,
                      KeyPackError, QueryTimeout, RouteExhausted, ShardFailure)
-from .lsm import LSMStore, ScanStats, VirtualSSTable
+from .lsm import LSMStore, ScanStats
+from .placement import (BlockShard, chip_blocks, make_scan_mesh,
+                        place_shards, range_partition, scan_launch_shape,
+                        scan_shard_devices)
 from .relation import ColType, Column
 from .skipping import Verdict
 
@@ -76,47 +79,8 @@ _PENDING = object()
 
 
 # ---------------------------------------------------------------------------
-# Range partitioning
+# Partial merge topology
 # ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class BlockShard:
-    """One shard's contiguous block range [lo_block, hi_block) of the
-    baseline (== one pk range, since baseline blocks are pk-ordered)."""
-
-    shard_id: int
-    lo_block: int
-    hi_block: int
-    n_rows: int
-
-    @property
-    def n_blocks(self) -> int:
-        return self.hi_block - self.lo_block
-
-    def block_ids(self) -> range:
-        return range(self.lo_block, self.hi_block)
-
-
-def range_partition(base: VirtualSSTable, n_shards: int) -> List[BlockShard]:
-    """Split the baseline's blocks into ``n_shards`` contiguous ranges of
-    near-equal row weight, read off the skipping-index leaf sketches (no
-    data access).  Shards may be empty when there are fewer blocks than
-    shards."""
-    if n_shards < 1:
-        raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-    nb = base.n_blocks
-    if nb == 0:
-        return [BlockShard(s, 0, 0, 0) for s in range(n_shards)]
-    weights = base.cols[base.schema.pk].index.leaf_counts()
-    cum = np.concatenate([[0], np.cumsum(weights)])
-    total = int(cum[-1])
-    cuts = [int(np.searchsorted(cum, total * s / n_shards, side="left"))
-            for s in range(1, n_shards)]
-    edges = np.maximum.accumulate(np.asarray([0] + cuts + [nb]))
-    return [BlockShard(s, int(edges[s]), int(edges[s + 1]),
-                       int(cum[edges[s + 1]] - cum[edges[s]]))
-            for s in range(n_shards)]
 
 
 def tree_reduce(parts: Sequence[Any], combine: Callable[[Any, Any], Any]):
@@ -860,8 +824,8 @@ class ShardedScanExecutor:
           tree-reduce on device (psum/pmin/pmax), so the host receives one
           already-merged accumulator — and, for pushable top-k shapes,
           only its first ``limit`` non-empty groups.
-        * **host** — the legacy per-shard kernel launches (round-robin
-          device placement, async dispatch) with a host-side tree-merge:
+        * **host** — the legacy per-shard kernel launches (each on its
+          shard's device, async dispatch) with a host-side tree-merge:
           counts/sums add, mins/maxs fold — the same combination rule as
           ``GroupedPartial.merge``.
 
@@ -901,10 +865,17 @@ class ShardedScanExecutor:
                 if est is not None else 1)
         stats.device_tile_blocks = tile
         from ..kernels import ops
-        from ..launch.mesh import make_scan_mesh, scan_shard_devices
-        active = [s for s in shards if s.n_blocks]
-        mesh = make_scan_mesh(len(active))
-        stats.n_devices = int(mesh.devices.size)
+        base = store.baseline
+        mesh = make_scan_mesh(base.n_blocks)
+        stats.n_devices = n_chips = int(mesh.devices.size)
+        if n_chips > 1:
+            # the generation's fixed block->chip placement: every chip
+            # scans its own partition, zero-count padding where it is empty
+            active = place_shards(base, len(shards), n_chips)
+            stats.n_shards = len(active)
+        else:
+            active = [s for s in shards if s.n_blocks]
+        stats.chip_blocks = chip_blocks(active, block_mask, n_chips)
         route = self.device_route or cost.choose_device_route(
             est, stats.n_devices, len(active))
         if route == "collective":
@@ -959,18 +930,19 @@ class ShardedScanExecutor:
                 stats.device_route = ""
                 stats.blocks_skipped = 0
                 stats.blocks_scanned = 0
-                stats.n_devices = 0
+                stats.n_devices = stats.chip_blocks = 0
                 return None
             if verdict == "probe":
                 stats.degraded.append(cost.breaker_note(
                     "per-shard-device", "probe",
                     "attempting per-shard launches"))
             try:
-                devices = scan_shard_devices(len(shards), mesh)
-                launched = launch_shard_kernels(plan, stage, active,
-                                                block_mask, devices, tile,
-                                                deadline=deadline,
-                                                stats=stats)
+                # shard i keeps its chip; an empty one has nothing to launch
+                devices = scan_shard_devices(len(active), mesh)
+                launched = launch_shard_kernels(
+                    plan, stage, [s for s in active if s.n_blocks],
+                    block_mask, devices, tile, deadline=deadline,
+                    stats=stats)
                 partials = [tuple(np.asarray(x) for x in o)
                             for o in launched]
                 out = tree_reduce(partials, device_partial_combine) + (None,)
@@ -985,7 +957,7 @@ class ShardedScanExecutor:
                 stats.device_route = ""
                 stats.blocks_skipped = 0
                 stats.blocks_scanned = 0
-                stats.n_devices = 0
+                stats.n_devices = stats.chip_blocks = 0
                 return None
         g_cnt, g_sums, g_mins, g_maxs, g_ids = out
         if g_ids is None:          # top-k-sliced runs record total already
@@ -1011,6 +983,13 @@ class ShardedScanExecutor:
             "collective", ops.sharded_scan_agg, deltas, bases, counts,
             plan.lo, plan.hi, codes, values, bmask, stats=stats,
             ndv=stage.ndv, mesh=mesh, coalesce=tile, topk=k or 0)
+        S, nbp = deltas.shape[:2]
+        stats.pad_blocks = S * nbp - sum(s.n_blocks for s in active)
+        n_chips = int(mesh.devices.size)
+        if n_chips > 1:     # each chip's group partials enter the reduce
+            stats.collective_bytes = (int(np.prod(stage.ndv))
+                                      * (1 + 3 * values.shape[2]) * 4
+                                      * n_chips)
         if k is not None:
             g_ids, g_cnt, g_sums, g_mins, g_maxs, total = out
             stats.actual_rows = int(total)
@@ -1058,7 +1037,6 @@ def stack_device_stage(stage, shards: Sequence[BlockShard],
     must never span a shard boundary, or padding blocks in the middle of a
     tile would break the kernel's valid-rows-prefix invariant.  Shared by
     ``ShardedScanExecutor._device_collective`` and the route benchmark."""
-    from ..launch.mesh import scan_launch_shape
     with spans.span("ob.stack"):
         _, S = scan_launch_shape(len(shards), mesh)
         nbp = max(s.n_blocks for s in shards)
@@ -1086,13 +1064,14 @@ def launch_shard_kernels(plan, stage, shards: Sequence[BlockShard],
                          block_mask: np.ndarray, devices, tile: int = 1,
                          deadline=None, stats=None):
     """Per-shard-launch device route: dispatch the fused kernel for every
-    shard's block slice (round-robin placement by shard id) and return the
-    raw per-shard outputs.  Every shard's launch is compiled first, so a
-    compile error fails the query before anything runs; then every kernel
-    is dispatched before any result is blocked on — jax dispatch is async,
-    so on a multi-device mesh the shards overlap — and a runtime fault of
-    any of them raises ``KernelLaunchError``.  The per-query ``deadline``
-    is checked between launches so ``deadline_s`` binds on this route too.
+    shard's block slice (on ``devices[shard_id]``, modulo their count) and
+    return the raw per-shard outputs.  Every shard's launch is compiled
+    first, so a compile error fails the query before anything runs; then
+    every kernel is dispatched before any result is blocked on — jax
+    dispatch is async, so on a multi-device mesh the shards overlap — and
+    a runtime fault of any of them raises ``KernelLaunchError``.  The
+    per-query ``deadline`` is checked between launches so ``deadline_s``
+    binds on this route too.
     Shared by ``ShardedScanExecutor._try_device`` and the route benchmark,
     so the bench always measures the loop the engine runs."""
     import jax

@@ -40,42 +40,6 @@ def make_smoke_mesh(shape: Tuple[int, ...] = (2, 2),
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_scan_mesh(n_shards: Optional[int] = None) -> jax.sharding.Mesh:
-    """1-D ``'scan'`` mesh for the sharded scan fan-out
-    (core/partition.py): one axis over the available devices, clamped to
-    the logical shard count.  On a real multi-chip host the axis is a real
-    multi-device axis and the single-launch collective route
-    (``kernels.fused_scan_agg.sharded_scan_agg``) tree-reduces partials
-    across it with psum/pmin/pmax; on a single-device host this
-    degenerates to a (1,) mesh and the fan-out runs its shards
-    sequentially inside one launch."""
-    ndev = len(jax.devices())
-    size = max(1, min(n_shards or ndev, ndev))
-    return jax.make_mesh((size,), ("scan",), axis_types=(AxisType.Auto,))
-
-
-def scan_launch_shape(n_shards: int,
-                      mesh: Optional[jax.sharding.Mesh] = None
-                      ) -> Tuple[jax.sharding.Mesh, int]:
-    """Mesh + padded logical-shard count for the single-launch collective
-    fan-out: the shard count rounds up to a multiple of the 'scan' axis
-    size so the [S, ...] staging splits evenly across devices (padding
-    shards are zero-count and masked off inside the kernel)."""
-    mesh = mesh if mesh is not None else make_scan_mesh(n_shards)
-    size = int(mesh.devices.size)
-    return mesh, -(-max(n_shards, 1) // size) * size
-
-
-def scan_shard_devices(n_shards: int,
-                       mesh: Optional[jax.sharding.Mesh] = None) -> list:
-    """Round-robin assignment of logical scan shards onto the scan mesh's
-    devices (shard i -> device i mod mesh size) — the per-shard-launch
-    (host-merge) device route."""
-    mesh = mesh if mesh is not None else make_scan_mesh(n_shards)
-    devs = list(mesh.devices.reshape(-1))
-    return [devs[i % len(devs)] for i in range(n_shards)]
-
-
 def make_rules(cfg: ModelConfig, shape: Optional[ShapeConfig],
                mesh: Optional[jax.sharding.Mesh]) -> MeshRules:
     """The per-cell sharding policy (single source of truth for the dry-run).

@@ -267,7 +267,7 @@ def test_sharded_device_falls_back_with_incremental():
 
 
 def test_scan_mesh_shard_devices():
-    from repro.launch.mesh import make_scan_mesh, scan_shard_devices
+    from repro.core.placement import make_scan_mesh, scan_shard_devices
     mesh = make_scan_mesh(4)
     assert mesh.axis_names == ("scan",)
     devs = scan_shard_devices(4, mesh)

@@ -1,6 +1,8 @@
 """The span recorder (``core/spans.py``) and the spans and counters of the
 served query path: which spans a request opens, where they nest, which
-request they carry, and the host bytes a device launch hands over."""
+request they carry, the host bytes a device launch hands over, and on a
+4-device mesh the placement's ``ob.place`` span and its counters."""
+import json
 import sys
 import threading
 import time
@@ -183,3 +185,139 @@ def test_h2d_bytes_equals_the_launch_arguments_bytes(rng, monkeypatch):
     import jax.numpy as jnp
     assert pushdown.host_bytes([jnp.zeros((8, 4)), np.zeros(3, bool), 5]) \
         == 3 + 4
+
+
+# the placement's span and counters on a 4-device mesh, as one subprocess:
+# per case, each collective launch's shard ranges, block mask and stacked
+# shape beside the counters of its answer
+PLACED = '''
+import json, sys
+sys.path.insert(0, ".")
+import numpy as np
+from repro.core import partition, spans
+from repro.core.engine import QAgg, Query
+from repro.core.partition import ShardedScanExecutor
+from repro.core.relation import Predicate, PredOp
+from tests.test_pushdown import make_store
+from tests.test_serving import GROUPED_Q
+
+seen = []
+stack = partition.stack_device_stage
+
+
+def keep(stage, shards, block_mask, mesh, tile=1):
+    out = stack(stage, shards, block_mask, mesh, tile)
+    seen.append({"ranges": [[s.lo_block, s.hi_block] for s in shards],
+                 "mask": np.asarray(block_mask).astype(int).tolist(),
+                 "shape": list(out[0][0].shape), "ndv": list(stage.ndv),
+                 "V": int(out[0][4].shape[2]),
+                 "D": int(mesh.devices.size)})
+    return out
+
+
+partition.stack_device_stage = keep
+spans.enable(True)
+PK_Q = Query(preds=(Predicate("k", PredOp.BETWEEN, 40, 200),),
+             group_by=("g",), aggs=(QAgg("count", None, "n"),
+                                    QAgg("sum", "v", "sv"),
+                                    QAgg("min", "v", "mn")))
+out = {"cases": [], "place": []}
+for n, shards, q in ((512, 4, GROUPED_Q), (512, 8, GROUPED_Q),
+                     (480, 4, GROUPED_Q), (512, 1, PK_Q)):
+    store = make_store(np.random.default_rng(5), n=n, block_rows=32,
+                       dml=False)
+    ex = ShardedScanExecutor(n_shards=shards, device=True,
+                             device_route="collective")
+    _, st = ex.execute_stats(store, q)
+    out["cases"].append(dict(seen.pop(), blocks=store.baseline.n_blocks,
+                             chip_blocks=st.chip_blocks,
+                             pad_blocks=st.pad_blocks,
+                             collective_bytes=st.collective_bytes,
+                             n_devices=st.n_devices))
+spans.drain()
+# one generation, three fan-outs; then a compaction, then two more
+store = make_store(np.random.default_rng(6), n=512, block_rows=32, dml=False)
+for shards in (1, 4, 6):
+    ShardedScanExecutor(n_shards=shards, device=True,
+                        device_route="collective").execute_stats(store,
+                                                                 GROUPED_Q)
+out["place"].append(sum(s.name == "ob.place" for s in spans.drain()[0]))
+store.insert({"k": 10_000, "g": 1, "d": 100, "v": 1.0, "s": "beta"})
+store.major_compact()
+for shards in (2, 8):
+    ShardedScanExecutor(n_shards=shards, device=True,
+                        device_route="collective").execute_stats(store,
+                                                                 GROUPED_Q)
+out["place"].append(sum(s.name == "ob.place" for s in spans.drain()[0]))
+print("RESULT " + json.dumps(out))
+'''
+
+
+@pytest.fixture(scope="module")
+def placed():
+    from tests.test_distributed import run_py
+    line = [ln for ln in run_py(PLACED, ndev=4).splitlines()
+            if ln.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
+
+
+def test_place_span_is_recorded_once_per_baseline_generation(placed):
+    assert placed["place"] == [1, 1]
+
+
+@pytest.mark.parametrize("case", range(4))
+def test_chip_pad_and_collective_counters_are_the_shape_arithmetic(
+        placed, case):
+    c = placed["cases"][case]
+    d, (s, nbp, bk) = c["D"], c["shape"]
+    assert c["n_devices"] == d == 4 and s == len(c["ranges"]) and s % d == 0
+    per_chip = [0] * d
+    for i, (lo, hi) in enumerate(c["ranges"]):
+        per_chip[i * d // s] += sum(c["mask"][lo:hi])
+    assert c["chip_blocks"] == max(per_chip)
+    assert c["pad_blocks"] == s * nbp - c["blocks"]
+    assert c["collective_bytes"] == (int(np.prod(c["ndv"]))
+                                     * (1 + 3 * c["V"]) * 4 * d)
+
+
+def test_pad_blocks_zero_when_shards_even(placed):
+    even4, even8, uneven, pruned = placed["cases"]
+    # 16 blocks: four chip partitions of 4, cut in 1 or 2 shards each
+    for c in (even4, even8):
+        assert c["blocks"] == 16 and c["pad_blocks"] == 0
+        assert c["chip_blocks"] == 4
+    # 15 blocks: partitions of 4, 4, 4 and 3; the last chip pads one
+    assert uneven["blocks"] == 15 and uneven["pad_blocks"] == 1
+    # k in [40, 200] keeps blocks 1..6: three on each of the first chips
+    assert pruned["chip_blocks"] == 3 and pruned["pad_blocks"] == 0
+    # g has 6 values, one value column: 6 x (1 + 3) x 4 B from each chip
+    assert even4["collective_bytes"] == 6 * 4 * 4 * 4
+
+
+def test_first_answers_racing_place_a_generation_once(rng, recording):
+    """Worker threads asking for a new generation's placement at once all
+    get the same partitions, computed and recorded as ``ob.place`` once."""
+    from repro.core import placement
+    base = make_store(rng, n=512, dml=False).baseline
+    n_threads, got = 32, []
+    start = threading.Barrier(n_threads)
+
+    def ask():
+        start.wait(timeout=60)
+        got.append(placement.chip_partitions(base, 4))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=ask) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(got) == n_threads and all(p is got[0] for p in got)
+    assert [s.n_blocks for s in got[0]] == [4, 4, 4, 4]
+    names = [s.name for s in spans.drain()[0]]
+    assert names.count("ob.place") == 1
